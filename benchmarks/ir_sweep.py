@@ -20,9 +20,9 @@ Two sweeps, two acceptance gates:
   must be >= 2x faster than the numpy reference on this grid (CPU jit
   counts); the Pallas backend runs in interpret mode for functional
   parity only (its wall time on CPU is the interpreter's, not the
-  kernel's) -- a compiled-mode (``interpret=False``) probe runs once and
-  its outcome is recorded in the payload, so the kernel's reference-only
-  status on CPU-only hosts is a measurement, not an assumption.
+  kernel's) -- a compiled-mode (``interpret=False``) probe runs once.
+  On the CPU its refusal is recorded in the payload; on an accelerator
+  a failure to compile fails the run.
   ``run.py`` dumps these numbers to ``BENCH_backends.json`` for the
   cross-PR perf trajectory.
 
@@ -564,9 +564,7 @@ def backend_throughput(quick: bool = False) -> dict:
             "on the large grid (acceptance gate is >= 2x)"
         )
     # Compiled-pallas probe: interpret=False compiles the actual Mosaic/
-    # Triton kernel, which needs a TPU/GPU backend.  On CPU-only hosts
-    # the attempt fails; the failure string is recorded so the kernel's
-    # reference-only status (DESIGN.md section 17) stays a measurement.
+    # Triton kernel, which needs a TPU/GPU backend (see the probe).
     payload["pallas_compiled"] = _pallas_compiled_probe()
     # The INDEPENDENT-mode grid gate rides along in the same payload so
     # BENCH_backends.json tracks both batching trajectories per PR,
@@ -579,11 +577,12 @@ def backend_throughput(quick: bool = False) -> dict:
 def _pallas_compiled_probe() -> dict:
     """Try the pallas kernel with ``interpret=False`` on a small batch.
 
-    Succeeds only where pallas can lower for the local accelerator
-    (TPU/GPU).  On CPU-only hosts this records the failure string --
-    the documented basis for keeping the kernel at reference status
-    until accelerator CI exists.
+    On the CPU, Pallas refuses anything but interpret mode; that refusal
+    is recorded as data.  On an accelerator any failure to lower,
+    compile or run propagates and fails the benchmark.
     """
+    import jax
+
     from repro.core.ir.backends import PallasBackend
 
     probe = [
@@ -597,14 +596,16 @@ def _pallas_compiled_probe() -> dict:
         backend = PallasBackend(interpret=False)
     except BackendUnavailable as exc:
         return {"available": False, "error": str(exc)}
+    packed = pack_instances(probe, None)
     try:
-        packed = pack_instances(probe, None)
         backend.derive_timing(packed)  # compile + run
-        t0 = time.perf_counter()
-        result = backend.derive_timing(packed)
-        warm_ms = (time.perf_counter() - t0) * 1e3
-    except Exception as exc:  # lowering fails off-accelerator
+    except ValueError as exc:
+        if jax.default_backend() != "cpu" or "interpret mode" not in str(exc):
+            raise
         return {"available": False, "error": f"{type(exc).__name__}: {exc}"}
+    t0 = time.perf_counter()
+    result = backend.derive_timing(packed)
+    warm_ms = (time.perf_counter() - t0) * 1e3
     ref = get_backend("numpy").derive_timing(pack_instances(probe, None))
     err = float(np.max(np.abs(result.cct - ref.cct)))
     return {

@@ -128,9 +128,9 @@ def run() -> list[tuple[str, float, str]]:
         for k in range(32)
     ]
     packed = pack_instances(instances, None)
-    from jax.experimental import enable_x64
+    from repro.core.ir import x64
 
-    with enable_x64():
+    with x64():
         fn = lambda: timing_scan(packed, interpret=True)
         jax.block_until_ready(fn()[0])
         t0 = time.perf_counter()

@@ -48,12 +48,14 @@ import time
 _REPO_ROOT = pathlib.Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(_REPO_ROOT / "src"))
 
+from repro import use_compile_cache  # noqa: E402
 from repro.obs import get_logger  # noqa: E402
 
 log = get_logger("benchmarks")
 
 
 def main() -> None:
+    use_compile_cache()
     from benchmarks import (
         fig5_motivation,
         fig7_cct_vs_msgsize,

@@ -25,14 +25,15 @@ from repro.core import (
 )
 from repro.core.planner import profile_train_step
 from repro.models.lm import _decoder_specs
-from repro.sharding.rules import MeshContext, abstract_mesh_compat
+from jax.sharding import AbstractMesh
+from repro.sharding.rules import MeshContext
 
 
 def run() -> list[tuple[str, float, str]]:
     cfg = get_config("qwen2_moe_a2_7b").replace(
         moe_token_slice=True, sequence_parallel=True
     )
-    mesh = abstract_mesh_compat((16, 16), ("data", "model"))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     ctx = MeshContext(mesh=mesh, dp_axes=("data",))
     cell = shape_cell("train_4k")
     specs = _decoder_specs(cfg, ctx)

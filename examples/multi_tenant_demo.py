@@ -20,6 +20,7 @@ renders JSON lines).
 import argparse
 import contextlib
 
+from repro import use_compile_cache
 from repro.configs.registry import get_config
 from repro.core import OpticalFabric, get_pattern, swot_schedule
 from repro.obs import ChromeTracer, get_logger
@@ -43,6 +44,7 @@ def scaled_mix(name: str):
 
 
 def main() -> None:
+    use_compile_cache()
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument(
         "--trace",

@@ -27,6 +27,9 @@ back-to-back), loadable at https://ui.perfetto.dev.
 
 import argparse
 
+from jax.sharding import AbstractMesh
+
+from repro import use_compile_cache
 from repro.configs.base import shape_cell
 from repro.configs.registry import get_config
 from repro.core import (
@@ -41,12 +44,13 @@ from repro.core.greedy import swot_greedy_chain
 from repro.core.planner import profile_train_step
 from repro.models.lm import _decoder_specs  # spec-only; no allocation
 from repro.obs import ChromeTracer, get_logger, trace_schedule
-from repro.sharding.rules import MeshContext, abstract_mesh_compat
+from repro.sharding.rules import MeshContext
 
 log = get_logger("optical_schedule_demo")
 
 
 def main() -> None:
+    use_compile_cache()
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument(
         "--backend",
@@ -77,7 +81,7 @@ def main() -> None:
     args = parser.parse_args()
     cfg = get_config("qwen2_moe_a2_7b")
     # AbstractMesh: the planner only needs mesh *shapes*; no devices.
-    mesh = abstract_mesh_compat((16, 16), ("data", "model"))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     ctx = MeshContext(mesh=mesh, dp_axes=("data",))
     specs = _decoder_specs(cfg, ctx)
     cell = shape_cell("train_4k")

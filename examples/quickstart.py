@@ -5,6 +5,7 @@
 
 import jax
 
+from repro import use_compile_cache
 from repro.configs.base import ShapeCell
 from repro.configs.registry import smoke_config
 from repro.core import (
@@ -23,6 +24,7 @@ log = get_logger("quickstart")
 
 
 def main() -> None:
+    use_compile_cache()
     # --- 1. SWOT: schedule a collective on an optical fabric ------------
     log.info("=== SWOT optical scheduling ===")
     shim = SwotShim(OpticalFabric(n_nodes=16, n_planes=4))

@@ -5,6 +5,7 @@
 
 import jax
 
+from repro import use_compile_cache
 from repro.configs.registry import smoke_config
 from repro.models.lm import build_model
 from repro.obs import get_logger
@@ -15,6 +16,7 @@ log = get_logger("serve_batched")
 
 
 def main() -> None:
+    use_compile_cache()
     ctx = single_device_context()
     cfg = smoke_config("qwen2_1_5b")
     model = build_model(cfg, ctx)

@@ -12,6 +12,7 @@ import argparse
 
 import jax
 
+from repro import use_compile_cache
 from repro.configs.base import ArchConfig, ShapeCell
 from repro.data.pipeline import SyntheticPipeline
 from repro.models.common import param_count
@@ -59,6 +60,7 @@ PRESETS = {
 
 
 def main() -> None:
+    use_compile_cache()
     parser = argparse.ArgumentParser()
     parser.add_argument("--steps", type=int, default=300)
     parser.add_argument("--preset", choices=PRESETS, default="100m")

@@ -454,7 +454,10 @@ def swot_greedy_chain_batch(
     would.  Because grid decisions are bitwise-identical to the
     per-instance greedy (the property the grid planners are pinned to),
     cell ``i``'s returned schedule is bitwise-identical to
-    ``swot_greedy_chain(*cells[i], plane_ready=plane_ready[i])``.
+    ``swot_greedy_chain(*cells[i], plane_ready=plane_ready[i])``.  That
+    holds on IEEE-float64 platforms only: once the batch takes the fused
+    planner on a TPU, whose float64 is emulated, a cell's schedule can
+    depend on how many jobs were granted with it (ROADMAP speed item 3).
 
     ``plane_ready`` entries must carry no positive offsets
     (``has_ready_offsets`` false): the grid planner models fresh planes

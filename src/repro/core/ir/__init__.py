@@ -8,7 +8,8 @@ The package splits the pre-refactor ``repro.core.ir`` module in two:
 * `repro.core.ir.backends` -- the per-step timing recurrence behind a
   backend interface: ``numpy`` (reference), ``jax`` (jit + scan over
   power-of-two buckets), ``pallas`` (blocked-scan kernel in
-  `repro.kernels.timing_scan`, interpret mode on CPU).
+  `repro.kernels.timing_scan`, interpret mode off the TPU), and `x64`,
+  the scoped 64-bit mode every device entry point runs under.
 * `repro.core.ir.fused`    -- the fused on-device grid planner: the
   whole per-step greedy loop (`repro.core.greedy.swot_greedy_grid`) as
   one jitted ``lax.scan``, bitwise-identical to the per-step numpy
@@ -33,6 +34,7 @@ from repro.core.ir.backends import (
     resolve_backend,
     select_backend_by_size,
     select_planner_by_size,
+    x64,
 )
 from repro.core.ir.engine import (
     _BIG,
@@ -90,4 +92,5 @@ __all__ = [
     "to_ir",
     "validate_ir",
     "waterfill_batch",
+    "x64",
 ]

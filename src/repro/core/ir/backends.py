@@ -19,12 +19,12 @@ oracle within `repro.core.tolerances`):
   ``jit``-compiled over the padded batch.  Inputs are padded to
   power-of-two *buckets* (batch, steps, planes) so the number of
   distinct compiled programs stays O(log^3) of the largest sweep, not
-  one per sweep shape.  Runs in float64 via a scoped ``enable_x64``.
+  one per sweep shape.  Runs in float64 under the scoped `x64` helper.
 * ``pallas`` -- the recurrence lowered as a *blocked scan* kernel
   (`repro.kernels.timing_scan`): the grid blocks the batch dimension,
   each program carries the (block, planes) plane state through a
-  ``fori_loop`` over steps.  On CPU it runs in interpret mode (the
-  tier-1 suite exercises it); on TPU set ``REPRO_PALLAS_INTERPRET=0``.
+  ``fori_loop`` over steps.  It runs in interpret mode off the TPU (the
+  tier-1 suite exercises it) and is compiled on the TPU.
 
 Select a backend per call (``batch_evaluate(..., backend="jax")``) or
 process-wide with the ``REPRO_IR_BACKEND`` env var; unset means numpy so
@@ -44,7 +44,6 @@ from repro.core.ir.engine import (
 )
 from repro.core.knobs import (  # noqa: F401  (compat re-exports)
     ENV_IR_BACKEND as ENV_BACKEND,
-    ENV_PALLAS_INTERPRET,
 )
 from repro.core.tolerances import EPS_VOLUME, REL_TOL, TOL
 
@@ -280,6 +279,16 @@ def _require_jax():
     return jax
 
 
+def x64():
+    """Context manager scoping 64-bit floats and ints for device programs.
+
+    Every jax, Pallas and fused-planner entry point runs under it: the
+    parity contract is with the float64 numpy reference, while the
+    process-wide JAX default stays 32-bit for everything else.
+    """
+    return _require_jax().enable_x64(True)
+
+
 def _build_jax_timing(attribution: bool = False) -> Callable:
     """The scan-lowered recurrence (built lazily so numpy users never
     import jax).
@@ -450,13 +459,11 @@ class JaxBackend(TimingBackend):
     def derive_timing(
         self, packed: dict[str, np.ndarray], attribution: bool = False
     ) -> BatchResult:
-        from jax.experimental import enable_x64
-
         fn = self._fns.get(attribution)
         if fn is None:
             fn = self._fns[attribution] = _build_jax_timing(attribution)
         padded, (b, p) = self._padded(packed)
-        with enable_x64():
+        with x64():
             out = fn(
                 padded["vol"], padded["step_vol"], padded["step_cfg"],
                 padded["step_mask"], padded["plane_mask"], padded["bw"],
@@ -480,13 +487,13 @@ class JaxBackend(TimingBackend):
 
 
 # ---------------------------------------------------------------------------
-# Pallas backend: blocked-scan kernel (interpret mode on CPU)
+# Pallas backend: blocked-scan kernel (interpret mode off the TPU)
 # ---------------------------------------------------------------------------
 class PallasBackend(TimingBackend):
     """Blocked-scan Pallas kernel (`repro.kernels.timing_scan`).
 
-    Interpret mode (the CPU fallback tier-1 tests exercise) is the
-    default; set ``REPRO_PALLAS_INTERPRET=0`` on a real TPU host.
+    Compiled on the TPU, interpreted on every other platform (the path
+    the CPU tests exercise).  ``interpret`` overrides that for tests.
     """
 
     name = "pallas"
@@ -505,25 +512,22 @@ class PallasBackend(TimingBackend):
             ) from exc
 
         self._kernel = timing_scan.timing_scan
-        # None = follow the env var *per call*: get_backend caches the
-        # instance process-wide, so binding the env value here would
-        # silently freeze whatever was set at first instantiation.
         self._interpret_override = interpret
 
     @property
     def interpret(self) -> bool:
         if self._interpret_override is not None:
             return self._interpret_override
-        return knobs.pallas_interpret()
+        import jax
+
+        return jax.default_backend() != "tpu"
 
     def derive_timing(
         self, packed: dict[str, np.ndarray], attribution: bool = False
     ) -> BatchResult:
-        from jax.experimental import enable_x64
-
         b, s, p = packed["vol"].shape
         padded = pad_packed(packed, _bucket(b), s, _bucket(p))
-        with enable_x64():
+        with x64():
             out = self._kernel(
                 padded, interpret=self.interpret, attribution=attribution
             )
@@ -652,8 +656,12 @@ def select_backend_by_size(
 # Grid-cell count at and above which ``swot_greedy_grid`` / ``plan_grid``
 # auto-select the FUSED on-device planner (`repro.core.ir.fused`): the
 # whole per-step greedy loop as one jitted lax.scan.  Below it the
-# per-step numpy loop wins (trace+compile does not amortize; the two are
-# bitwise-identical, so the threshold is purely a performance knob).
+# per-step numpy loop wins (trace+compile does not amortize).  Where the
+# device's float64 is IEEE (the CPU) the two are bitwise-identical, so
+# there the threshold is purely a performance knob.  On a TPU, whose
+# float64 is emulated, the fused planner takes other near-tie decisions,
+# so a cell's plan depends on whether its grid crossed the threshold
+# (ROADMAP speed item 3).
 # Override with the env var; <= 0 disables fused auto-selection.
 ENV_FUSED_PLANNER_THRESHOLD = knobs.ENV_FUSED_PLANNER_THRESHOLD
 DEFAULT_FUSED_PLANNER_THRESHOLD = knobs.DEFAULT_FUSED_PLANNER_THRESHOLD

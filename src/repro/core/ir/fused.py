@@ -14,7 +14,10 @@ chosen per-step splits.
 
 The contract is *bitwise* parity with the per-step numpy planner (which
 is itself bitwise-pinned to the per-instance reference): every float op
-below mirrors its numpy twin operation for operation.  The places where
+below mirrors its numpy twin operation for operation.  It holds where the
+device's float64 is IEEE (the CPU).  A TPU emulates float64 with pairs
+of float32s, so there the ops round differently and near-tie decisions
+differ (ROADMAP speed item 3).  The places where
 a naive lowering would break the bit pattern (or the performance):
 
 * XLA:CPU contracts ``a * b + c`` into a single-rounding FMA, a 1-ULP
@@ -39,10 +42,10 @@ a naive lowering would break the bit pattern (or the performance):
   loops with live masking; every masked iteration is arithmetically
   inert, so the carried state stays identical.
 
-Everything runs in float64 via a scoped ``enable_x64`` (the same policy
-as the jax timing backend).  Entry points return the per-step ``chosen``
-tuples the numpy loop accumulates, so `repro.core.greedy` materializes
-Decisions through one shared epilogue for both planners.
+Everything runs in float64 under the scoped `x64` helper (the same
+policy as the jax timing backend).  Entry points return the per-step
+``chosen`` tuples the numpy loop accumulates, so `repro.core.greedy`
+materializes Decisions through one shared epilogue for both planners.
 """
 
 from __future__ import annotations
@@ -52,6 +55,7 @@ from typing import TYPE_CHECKING
 
 import numpy as np
 
+from repro.core.ir.backends import x64
 from repro.core.ir.engine import _BIG
 from repro.core.tolerances import EPS as _EPS
 from repro.core.tolerances import EPS_VOLUME as _EPS_VOLUME
@@ -594,6 +598,37 @@ def _base_tables(st: "_GridState") -> dict:
     }
 
 
+def _chain_tables(st: "_GridState", with_bypass: bool) -> dict:
+    """Every table the fused CHAIN scan reads (call under `x64`)."""
+    import jax.numpy as jnp
+
+    tab = _base_tables(st)
+    tab.update(
+        bw_sum=jnp.asarray(st.bw_sum, jnp.float64),
+        suffix_vol=jnp.asarray(st.suffix_vol, jnp.float64),
+        suffix_changes=jnp.asarray(st.suffix_changes, jnp.int64),
+        prev_same=jnp.asarray(st.prev_same, jnp.int64),
+        cand_mask=jnp.asarray(st.cand_mask, bool),
+        cand_inst=jnp.asarray(st.cand_inst, jnp.int64),
+    )
+    # Dynamic rows: soonest-free prefixes of sizes 0..3, refreshed per
+    # step on device.  `dyn_size` holds the prefix size per dynamic row
+    # (-1 for static rows, which never match a rank).
+    dyn_row = np.zeros(st.cand_inst.shape[0], dtype=bool)
+    dyn_size = np.full(st.cand_inst.shape[0], -1, dtype=np.int64)
+    for bi in st.dyn_insts:
+        start = int(st.cand_start[bi])
+        dyn_row[start:start + 4] = True
+        dyn_size[start:start + 4] = np.arange(4)
+    tab.update(
+        dyn_row=jnp.asarray(dyn_row),
+        dyn_size=jnp.asarray(dyn_size),
+    )
+    if with_bypass:
+        tab["depth_tab"] = jnp.asarray(st.depth_tab, jnp.int64)
+    return tab
+
+
 def fused_chain_grid_chosen(
     st: "_GridState", rollout_horizon: int
 ) -> list[tuple[np.ndarray, np.ndarray, np.ndarray]]:
@@ -606,37 +641,11 @@ def fused_chain_grid_chosen(
     steps.
     """
     _require_jax()
-    from jax.experimental import enable_x64
-
     with_bypass = st.bypass_depth >= 2 and st.depth_tab.shape[1] > 0
-    with enable_x64():
-        import jax.numpy as jnp
-
-        tab = _base_tables(st)
-        tab.update(
-            bw_sum=jnp.asarray(st.bw_sum, jnp.float64),
-            suffix_vol=jnp.asarray(st.suffix_vol, jnp.float64),
-            suffix_changes=jnp.asarray(st.suffix_changes, jnp.int64),
-            prev_same=jnp.asarray(st.prev_same, jnp.int64),
-            cand_mask=jnp.asarray(st.cand_mask, bool),
-            cand_inst=jnp.asarray(st.cand_inst, jnp.int64),
+    with x64():
+        ys = _chain_scan(rollout_horizon, with_bypass)(
+            _chain_tables(st, with_bypass)
         )
-        # Dynamic rows: soonest-free prefixes of sizes 0..3, refreshed
-        # per step on device.  `dyn_size` holds the prefix size per
-        # dynamic row (-1 for static rows, which never match a rank).
-        dyn_row = np.zeros(st.cand_inst.shape[0], dtype=bool)
-        dyn_size = np.full(st.cand_inst.shape[0], -1, dtype=np.int64)
-        for bi in st.dyn_insts:
-            start = int(st.cand_start[bi])
-            dyn_row[start:start + 4] = True
-            dyn_size[start:start + 4] = np.arange(4)
-        tab.update(
-            dyn_row=jnp.asarray(dyn_row),
-            dyn_size=jnp.asarray(dyn_size),
-        )
-        if with_bypass:
-            tab["depth_tab"] = jnp.asarray(st.depth_tab, jnp.int64)
-        ys = _chain_scan(rollout_horizon, with_bypass)(tab)
         split_s = np.asarray(ys[0], dtype=np.float64)
         byph_s = np.asarray(ys[1], dtype=np.int64)
         feas_s = np.asarray(ys[2], dtype=bool)
@@ -656,9 +665,7 @@ def fused_independent_grid_chosen(
 ) -> list[tuple[np.ndarray, np.ndarray, np.ndarray]]:
     """Fused least-finish-time packing; per-step tuples as the numpy loop."""
     _require_jax()
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with x64():
         ys = _independent_scan(split_mode=False)(_base_tables(st))
         j_s = np.asarray(ys, dtype=np.int64)
     chosen = []
@@ -676,9 +683,7 @@ def fused_independent_split_grid_chosen(
 ) -> list[tuple[np.ndarray, np.ndarray]]:
     """Fused per-row-volume water-fill packing (INDEPENDENT split mode)."""
     _require_jax()
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with x64():
         ys = _independent_scan(split_mode=True)(_base_tables(st))
         split_s = np.asarray(ys, dtype=np.float64)
     chosen = []

@@ -25,7 +25,6 @@ from typing import Any
 # Environment-variable names (the public contract; referenced by CI and
 # docs, so renaming any of these is a breaking change).
 ENV_IR_BACKEND = "REPRO_IR_BACKEND"
-ENV_PALLAS_INTERPRET = "REPRO_PALLAS_INTERPRET"
 ENV_ARBITER_BACKEND_THRESHOLD = "REPRO_ARBITER_BACKEND_THRESHOLD"
 ENV_GRID_BACKEND_THRESHOLD = "REPRO_GRID_BACKEND_THRESHOLD"
 ENV_FUSED_PLANNER_THRESHOLD = "REPRO_FUSED_PLANNER_THRESHOLD"
@@ -33,7 +32,6 @@ ENV_LOG = "REPRO_LOG"
 
 # Defaults (single source of truth).
 DEFAULT_IR_BACKEND = "numpy"
-DEFAULT_PALLAS_INTERPRET = True
 # Equals the arbiter's release-candidate cap (_MAX_RELEASE_CANDIDATES):
 # exactly the maximum-size shrink batches flip to jax.  The arbiter
 # asserts the invariant at import.
@@ -48,7 +46,7 @@ class Knob:
     """One registered environment knob."""
 
     env: str
-    kind: str  # "str" | "int" | "bool"
+    kind: str  # "str" | "int"
     default: Any
     doc: str
 
@@ -72,10 +70,6 @@ class Knob:
                 raise ValueError(
                     f"{self.env} must be an integer, got {raw!r}"
                 ) from exc
-        if self.kind == "bool":
-            # Historical REPRO_PALLAS_INTERPRET semantics: "0" is the
-            # only falsy spelling; anything else (incl. "") is truthy.
-            return raw != "0"
         return raw
 
 
@@ -87,12 +81,6 @@ KNOBS: dict[str, Knob] = {
             "str",
             DEFAULT_IR_BACKEND,
             "process-wide default timing backend (numpy | jax | pallas)",
-        ),
-        Knob(
-            ENV_PALLAS_INTERPRET,
-            "bool",
-            DEFAULT_PALLAS_INTERPRET,
-            "run the Pallas kernel in interpret mode (set 0 on TPU/GPU)",
         ),
         Knob(
             ENV_ARBITER_BACKEND_THRESHOLD,
@@ -130,11 +118,6 @@ KNOBS: dict[str, Knob] = {
 def ir_backend() -> str:
     """The process-wide default timing-backend name."""
     return KNOBS[ENV_IR_BACKEND].value()
-
-
-def pallas_interpret() -> bool:
-    """Whether the Pallas kernel runs in interpret mode."""
-    return KNOBS[ENV_PALLAS_INTERPRET].value()
 
 
 def arbiter_backend_threshold() -> int:

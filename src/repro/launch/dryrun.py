@@ -49,7 +49,7 @@ from repro.launch.mesh import production_context
 from repro.models.common import is_spec
 from repro.models.lm import build_model
 from repro.optim.adamw import AdamWConfig, adamw_init
-from repro.sharding.rules import MeshContext, param_partition_specs, set_mesh_compat
+from repro.sharding.rules import MeshContext, param_partition_specs
 
 ARTIFACT_DIR = os.path.join("artifacts", "dryrun")
 
@@ -132,7 +132,7 @@ def run_cell(
     chips = ctx.mesh.size
     t0 = time.time()
     step_fn, inputs, model = _step_and_inputs(cfg, ctx, cell)
-    with set_mesh_compat(ctx.mesh):
+    with jax.set_mesh(ctx.mesh):
         lowered = jax.jit(step_fn, donate_argnums=(0,)).lower(*inputs)
         t_lower = time.time() - t0
         compiled = lowered.compile()

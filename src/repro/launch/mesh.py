@@ -10,13 +10,13 @@ from __future__ import annotations
 
 import jax
 
-from repro.sharding.rules import MeshContext, make_mesh_compat
+from repro.sharding.rules import MeshContext, make_auto_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_auto_mesh(shape, axes)
 
 
 def production_context(*, multi_pod: bool = False) -> MeshContext:

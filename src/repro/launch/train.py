@@ -83,10 +83,11 @@ def main() -> None:
     if shim is not None:
         # Plan against the production mesh shapes (AbstractMesh: the
         # planner reads shapes only), independent of the local run mesh.
-        from repro.sharding.rules import MeshContext, abstract_mesh_compat
+        from jax.sharding import AbstractMesh
+        from repro.sharding.rules import MeshContext
 
         plan_ctx = MeshContext(
-            mesh=abstract_mesh_compat((16, 16), ("data", "model")),
+            mesh=AbstractMesh((16, 16), ("data", "model")),
             dp_axes=("data",),
         )
         report = trainer.plan_optics(plan_ctx)

@@ -26,7 +26,6 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro.sharding.rules import shard_map_compat
 
 _NEG_INF = -1e30
 
@@ -313,7 +312,7 @@ def sharded_decode_attention(
         out = o_glob / jnp.maximum(l_glob, 1e-30)[..., None]
         return out.reshape(b, 1, hq, d).astype(q_blk.dtype)
 
-    return shard_map_compat(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), P(None, axis), P(None, axis), P()),

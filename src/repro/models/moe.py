@@ -42,7 +42,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.sharding.rules import shard_map_compat
 
 from repro.models.common import ParamSpec, activation
 
@@ -385,7 +384,7 @@ def moe_ffn(
     # 'model' by construction -- but the static varying-axes checker cannot
     # see through all_to_all.  The redundant per-row dispatch compute this
     # implies is a recorded Perf lever (EP token slicing, EXPERIMENTS.md).
-    y, aux, drop, counts_out = shard_map_compat(
+    y, aux, drop, counts_out = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(

@@ -902,7 +902,9 @@ class FabricArbiter:
         Cache hits install immediately; two or more *misses* that the
         instance-batched greedy can serve (greedy CHAIN, no ready
         offsets) are planned through ONE ``swot_greedy_chain_batch``
-        pass -- bitwise-identical schedules to the per-job path -- and
+        pass -- bitwise-identical schedules to the per-job path on
+        IEEE-float64 platforms; not on a TPU once the batch takes the
+        fused planner (ROADMAP speed item 3) -- and
         everything else falls back to per-job planning.  Plans install in
         grant order, so boundary events keep the legacy tie-break order.
         """

@@ -15,7 +15,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.lm import Model
-from repro.sharding.rules import set_mesh_compat
 
 
 @dataclasses.dataclass
@@ -84,7 +83,7 @@ class ServeEngine:
                 (tokens.shape[0], cfg.n_audio_frames, cfg.d_model),
                 jnp.bfloat16,
             )
-        with set_mesh_compat(self.model.ctx.mesh):
+        with jax.set_mesh(self.model.ctx.mesh):
             logits, cache = self._prefill(self.params, batch)
             self._record_step("prefill", tokens.shape[0], prompt_len)
             cache = self._grow(cache, tokens.shape[0])

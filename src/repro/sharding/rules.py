@@ -210,69 +210,22 @@ def param_named_shardings(
     )
 
 
-def make_mesh_compat(axis_shapes, axis_names) -> Mesh:
-    """``jax.make_mesh`` across JAX versions.
+def make_auto_mesh(axis_shapes, axis_names) -> Mesh:
+    """``jax.make_mesh`` with every axis Auto-sharded.
 
-    Newer JAX requires explicit ``axis_types`` (``jax.sharding.AxisType``);
-    older releases predate the enum and reject the keyword.  All our meshes
-    are Auto-sharded, so the explicit annotation is semantically a no-op.
+    ``jax.make_mesh`` defaults to Explicit axes; every mesh here relies on
+    GSPMD propagation from the rule-derived specs, so all axes are Auto.
     """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                axis_shapes,
-                axis_names,
-                axis_types=(axis_type.Auto,) * len(axis_names),
-            )
-        except TypeError:
-            pass  # make_mesh predates the axis_types keyword
-    return jax.make_mesh(axis_shapes, axis_names)
-
-
-def shard_map_compat(f, **kwargs):
-    """``jax.shard_map`` across JAX versions.
-
-    Older releases only ship ``jax.experimental.shard_map.shard_map``; the
-    keyword signature (mesh/in_specs/out_specs) is compatible.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-
-        if "check_vma" in kwargs:  # renamed from check_rep
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-    return sm(f, **kwargs)
-
-
-def abstract_mesh_compat(axis_shapes, axis_names):
-    """``jax.sharding.AbstractMesh`` across JAX versions.
-
-    Newer JAX takes ``(axis_shapes, axis_names)``; older releases take a
-    single tuple of ``(name, size)`` pairs.
-    """
-    try:
-        return jax.sharding.AbstractMesh(axis_shapes, axis_names)
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(axis_names, axis_shapes)))
-
-
-def set_mesh_compat(mesh: Mesh):
-    """``jax.set_mesh`` across JAX versions (context manager).
-
-    Older releases predate ``jax.set_mesh``; there ``Mesh`` itself is a
-    context manager establishing the implicit global mesh, which is what
-    every call site here needs.
-    """
-    setter = getattr(jax, "set_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    return mesh
+    return jax.make_mesh(
+        axis_shapes,
+        axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+    )
 
 
 def single_device_context() -> MeshContext:
     """1x1 mesh for smoke tests and single-host runs."""
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    mesh = make_auto_mesh((1, 1), ("data", "model"))
     return MeshContext(mesh=mesh, dp_axes=("data",))
 
 

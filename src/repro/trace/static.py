@@ -1,7 +1,7 @@
 """Static trace extraction: ArchConfig + mesh -> ``CollectiveTrace``.
 
 No devices and no compilation: the mesh is a ``jax.sharding.AbstractMesh``
-(`repro.sharding.rules.abstract_mesh_compat`), model parameter shapes come
+(`jax.sharding.AbstractMesh`), model parameter shapes come
 from the metadata-only spec builders (`repro.models.lm.build_model`), and
 the per-step collective set is the Phase-1 sharding profile
 (`repro.core.planner.profile_train_step` / ``profile_serve_step``) -- so
@@ -39,12 +39,13 @@ _BF16 = 2
 
 
 def _mesh_context(dp: int, tp: int, pod: int):
-    from repro.sharding.rules import MeshContext, abstract_mesh_compat
+    from jax.sharding import AbstractMesh
+    from repro.sharding.rules import MeshContext
 
     if pod >= 2:
-        mesh = abstract_mesh_compat((pod, dp, tp), ("pod", "data", "model"))
+        mesh = AbstractMesh((pod, dp, tp), ("pod", "data", "model"))
         return MeshContext(mesh, dp_axes=("pod", "data"))
-    mesh = abstract_mesh_compat((dp, tp), ("data", "model"))
+    mesh = AbstractMesh((dp, tp), ("data", "model"))
     return MeshContext(mesh, dp_axes=("data",))
 
 

@@ -26,7 +26,6 @@ from repro.train.checkpoint import (
     save_checkpoint,
 )
 from repro.train.loop import TrainState, Trainer, init_train_state
-from repro.sharding.rules import set_mesh_compat
 
 
 class InjectedFailure(RuntimeError):
@@ -76,7 +75,7 @@ def run_with_restarts(
             while int(state.step) < target_steps:
                 from repro.data.pipeline import shard_batch
 
-                with set_mesh_compat(trainer.model.ctx.mesh):
+                with jax.set_mesh(trainer.model.ctx.mesh):
                     batch = shard_batch(next(pipeline), trainer.model.ctx)
                     state, _metrics = trainer._jit(state, batch)
                 step = int(state.step)

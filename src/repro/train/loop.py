@@ -27,7 +27,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ArchConfig, ShapeCell
 from repro.models.lm import Model
 from repro.optim.adamw import AdamWConfig, adamw_init, adamw_update
-from repro.sharding.rules import MeshContext, param_named_shardings, set_mesh_compat
+from repro.sharding.rules import MeshContext, param_named_shardings
 
 Pytree = Any
 
@@ -188,7 +188,7 @@ class Trainer:
         from repro.train.checkpoint import save_checkpoint
 
         history = []
-        with set_mesh_compat(self.model.ctx.mesh):
+        with jax.set_mesh(self.model.ctx.mesh):
             for _ in range(n_steps):
                 batch = shard_batch(next(pipeline), self.model.ctx)
                 t0 = time.perf_counter()

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.sharding.rules import shard_map_compat
 
 Pytree = object
 
@@ -75,7 +74,7 @@ def gpipe_forward(
         outputs = lax.psum(outputs, axis)
         return outputs
 
-    return shard_map_compat(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(

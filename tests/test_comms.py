@@ -13,22 +13,21 @@ _SCRIPT = textwrap.dedent(
     from jax import lax
     from jax.sharding import PartitionSpec as P
     from repro.comms import algorithms as alg
-    from repro.sharding.rules import make_mesh_compat
-    from repro.sharding.rules import shard_map_compat
+    from repro.sharding.rules import make_auto_mesh
     from repro.comms.compression import (
         compressed_all_reduce, compress_decompress, wire_bytes)
 
-    mesh = make_mesh_compat((8,), ("x",))
+    mesh = make_auto_mesh((8,), ("x",))
 
     def run(body, x, out_specs=P("x")):
-        return jax.jit(shard_map_compat(
+        return jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=P("x"), out_specs=out_specs,
         ))(x)
 
     key = jax.random.PRNGKey(0)
     # --- AllReduce algorithms vs psum --------------------------------------
     x = jax.random.normal(key, (8, 3, 40))  # sharded dim 8 over axis x
-    want = np.asarray(jax.jit(shard_map_compat(
+    want = np.asarray(jax.jit(jax.shard_map(
         lambda v: lax.psum(v, "x"), mesh=mesh,
         in_specs=P("x"), out_specs=P("x")))(x))
     for name, fn in (("ring", alg.ring_all_reduce),
@@ -40,7 +39,7 @@ _SCRIPT = textwrap.dedent(
 
     # --- All-to-all algorithms vs lax.all_to_all ---------------------------
     y = jax.random.normal(key, (8, 8, 5))   # (ranks, chunks, payload)
-    want = np.asarray(jax.jit(shard_map_compat(
+    want = np.asarray(jax.jit(jax.shard_map(
         lambda v: lax.all_to_all(v, "x", split_axis=1, concat_axis=1,
                                  tiled=False),
         mesh=mesh, in_specs=P("x"), out_specs=P("x")))(y))
@@ -53,12 +52,12 @@ _SCRIPT = textwrap.dedent(
         print(f"{name}_alltoall OK")
 
     # --- Hierarchical all-reduce on a 2D mesh ------------------------------
-    mesh2 = make_mesh_compat((2, 4), ("pod", "data"))
+    mesh2 = make_auto_mesh((2, 4), ("pod", "data"))
     z = jax.random.normal(key, (8, 24))
-    want = np.asarray(jax.jit(shard_map_compat(
+    want = np.asarray(jax.jit(jax.shard_map(
         lambda v: lax.psum(v, ("pod", "data")), mesh=mesh2,
         in_specs=P(("pod", "data")), out_specs=P(("pod", "data"))))(z))
-    got = np.asarray(jax.jit(shard_map_compat(
+    got = np.asarray(jax.jit(jax.shard_map(
         lambda v: alg.hierarchical_all_reduce(v, "data", "pod"),
         mesh=mesh2, in_specs=P(("pod", "data")),
         out_specs=P(("pod", "data"))))(z))
@@ -71,7 +70,7 @@ _SCRIPT = textwrap.dedent(
     def _comp(v):
         out, err = compressed_all_reduce(v[0], "x")
         return out[None], err[None]
-    got_all, err = jax.jit(shard_map_compat(
+    got_all, err = jax.jit(jax.shard_map(
         _comp, mesh=mesh, in_specs=P("x"), out_specs=(P("x"), P("x"))))(g)
     # The ring sum is replicated by construction: every rank agrees.
     np.testing.assert_allclose(np.asarray(got_all[0]),

@@ -24,9 +24,9 @@ _SCRIPT = textwrap.dedent(
     from repro.analysis.roofline import model_flops_for, roofline_from_summary
     from repro.launch.dryrun import _abstract, _abstract_batch, _step_and_inputs
     from repro.sharding.rules import MeshContext
-    from repro.sharding.rules import make_mesh_compat, set_mesh_compat
+    from repro.sharding.rules import make_auto_mesh
 
-    mesh = make_mesh_compat((2, 4), ("data", "model"))
+    mesh = make_auto_mesh((2, 4), ("data", "model"))
     ctx = MeshContext(mesh=mesh, dp_axes=("data",))
 
     for arch in ("qwen3_4b", "qwen2_moe_a2_7b", "mamba2_130m"):
@@ -39,7 +39,7 @@ _SCRIPT = textwrap.dedent(
             from repro.models.lm import build_model
             model = build_model(cfg, ctx)
             step_fn, inputs, model = _step_and_inputs(cfg, ctx, cell)
-            with set_mesh_compat(mesh):
+            with jax.set_mesh(mesh):
                 lowered = jax.jit(step_fn, donate_argnums=(0,)).lower(*inputs)
                 compiled = lowered.compile()
                 mem = compiled.memory_analysis()
@@ -81,9 +81,10 @@ def test_sharding_rules_divisibility_fallback():
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from repro.sharding.rules import MeshContext, abstract_mesh_compat
+    from jax.sharding import AbstractMesh
+    from repro.sharding.rules import MeshContext
 
-    mesh = abstract_mesh_compat((2, 4, 4), ("pod", "data", "model"))
+    mesh = AbstractMesh((2, 4, 4), ("pod", "data", "model"))
     ctx = MeshContext(mesh=mesh, dp_axes=("pod", "data"))
     # 12 heads % 4 == 0 -> sharded; 6 heads % 4 != 0 -> replicated.
     assert ctx.spec_for((256, 12, 64), ("embed", "heads", "head_dim")) == P(
@@ -105,9 +106,10 @@ def test_fsdp_spec_adds_dp_axis():
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from repro.sharding.rules import MeshContext, abstract_mesh_compat, fsdp_spec
+    from jax.sharding import AbstractMesh
+    from repro.sharding.rules import MeshContext, fsdp_spec
 
-    mesh = abstract_mesh_compat((4, 4), ("data", "model"))
+    mesh = AbstractMesh((4, 4), ("data", "model"))
     ctx = MeshContext(mesh=mesh, dp_axes=("data",))
     # Attention weights with non-divisible heads: replicated by base
     # rules, FSDP shards the largest divisible dim over data.

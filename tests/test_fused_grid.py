@@ -21,7 +21,7 @@ code path a CPU-only host gets.
 
 import numpy as np
 import pytest
-from hypothesis import HealthCheck, given, settings
+from hypothesis import given, settings
 from hypothesis import strategies as st
 
 from repro.core import BatchInstance, OpticalFabric, batch_evaluate
@@ -32,6 +32,7 @@ from repro.core.ir.backends import (
     ENV_FUSED_PLANNER_THRESHOLD,
     get_backend,
     select_planner_by_size,
+    x64,
 )
 from repro.core.ir.engine import _BIG, pack_instances, waterfill_batch
 from repro.core.patterns import pairwise_alltoall, rabenseifner_allreduce
@@ -269,13 +270,12 @@ class TestPallasBypass:
 # Numeric primitives: bitwise parity eager AND under jit
 # ---------------------------------------------------------------------------
 class TestFusedPrimitives:
-    @pytest.fixture(autouse=True)
+    @pytest.fixture(autouse=True, scope="class")
     def _x64(self):
-        # The fused planner always runs under enable_x64 (bitwise parity
-        # with the float64 numpy loop is the whole contract); mirror it.
-        from jax.experimental import enable_x64
-
-        with enable_x64():
+        # The fused planner always runs under the x64 helper (bitwise
+        # parity with the float64 numpy loop is the whole contract);
+        # mirror it.  Class-scoped, so hypothesis examples share it.
+        with x64():
             yield
 
     def _rand(self, seed, shape, lo=0.0, hi=1.0):
@@ -312,13 +312,7 @@ class TestFusedPrimitives:
         want = np.argsort(order, axis=-1, kind="stable")
         assert np.array_equal(got, want)
 
-    # The autouse enable_x64 fixture is idempotent across examples, so
-    # the function-scoped-fixture health check does not apply.
-    @settings(
-        max_examples=25,
-        deadline=None,
-        suppress_health_check=[HealthCheck.function_scoped_fixture],
-    )
+    @settings(max_examples=25, deadline=None)
     @given(
         seed=st.integers(min_value=0, max_value=2**31),
         rows=st.integers(min_value=1, max_value=17),

@@ -93,9 +93,9 @@ _COLLECTIVE_SCRIPT = textwrap.dedent(
     import jax, jax.numpy as jnp
     from jax.sharding import PartitionSpec as P, NamedSharding
     from repro.analysis.hlo import analyze_hlo_text
-    from repro.sharding.rules import make_mesh_compat, set_mesh_compat
+    from repro.sharding.rules import make_auto_mesh
 
-    mesh = make_mesh_compat((2, 4), ("data", "model"))
+    mesh = make_auto_mesh((2, 4), ("data", "model"))
 
     def step(w, x):
         y = jnp.einsum("bd,df->bf", x, w)
@@ -103,7 +103,7 @@ _COLLECTIVE_SCRIPT = textwrap.dedent(
 
     w = jax.ShapeDtypeStruct((256, 512), jnp.float32)
     x = jax.ShapeDtypeStruct((32, 256), jnp.float32)
-    with set_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(step,
             in_shardings=(NamedSharding(mesh, P(None, "model")),
                           NamedSharding(mesh, P("data", None))),
@@ -126,7 +126,7 @@ _COLLECTIVE_SCRIPT = textwrap.dedent(
     for n in (2, 6):
         ws = jax.ShapeDtypeStruct((n, 256, 256), jnp.float32)
         x2 = jax.ShapeDtypeStruct((32, 256), jnp.float32)
-        with set_mesh_compat(mesh):
+        with jax.set_mesh(mesh):
             c = jax.jit(layered,
                 in_shardings=(NamedSharding(mesh, P("data", None)),
                               NamedSharding(mesh, P(None, None, "model"))),

@@ -60,7 +60,9 @@ def _backend_or_skip(name: str):
         pytest.skip(f"backend {name} unavailable: {exc}")
 
 
-@pytest.fixture(params=BACKEND_NAMES)
+# Module-scoped: the backends are process-wide singletons, and the
+# property tests below take the fixture under @given.
+@pytest.fixture(scope="module", params=BACKEND_NAMES)
 def backend(request):
     return _backend_or_skip(request.param)
 

@@ -104,7 +104,10 @@ def test_merge_is_associative_and_commutative(a, b, c):
     central = _hist(a + b + c)
     assert _state(left) == _state(central)
     for q in (0.5, 0.95, 0.99):
-        assert left.quantile(q) == central.quantile(q)
+        got, want = left.quantile(q), central.quantile(q)
+        # Empty histograms (hypothesis draws a = b = c = []) have no
+        # quantile: NaN on both sides.
+        assert got == want or (math.isnan(got) and math.isnan(want))
     assert math.isclose(
         left.sum, central.sum, rel_tol=1e-9, abs_tol=1e-12
     )

@@ -14,7 +14,7 @@ from repro.configs.base import ShapeCell
 from repro.configs.inputs import make_batch
 from repro.configs.registry import ARCH_IDS, get_config, smoke_config
 from repro.models.lm import build_model
-from repro.sharding.rules import single_device_context, set_mesh_compat
+from repro.sharding.rules import single_device_context
 
 CTX = single_device_context()
 TRAIN_CELL = ShapeCell("smoke_train", "train", 64, 2)
@@ -70,7 +70,7 @@ def test_long500k_skips_match_design():
 def test_train_step(arch):
     cfg, model, params = arch
     batch = make_batch(cfg, TRAIN_CELL, jax.random.PRNGKey(1))
-    with set_mesh_compat(CTX.mesh):
+    with jax.set_mesh(CTX.mesh):
         loss, metrics = jax.jit(model.loss_fn)(params, batch)
     assert np.isfinite(float(loss)), cfg.name
     assert float(loss) > 0
@@ -80,7 +80,7 @@ def test_train_step(arch):
 def test_grads_finite(arch):
     cfg, model, params = arch
     batch = make_batch(cfg, TRAIN_CELL, jax.random.PRNGKey(2))
-    with set_mesh_compat(CTX.mesh):
+    with jax.set_mesh(CTX.mesh):
         grads = jax.jit(
             jax.grad(lambda p, b: model.loss_fn(p, b)[0])
         )(params, batch)
@@ -102,7 +102,7 @@ def test_prefill_decode_consistency(arch):
     tokens = batch["tokens"]
     s = tokens.shape[1]
     k = 3
-    with set_mesh_compat(CTX.mesh):
+    with jax.set_mesh(CTX.mesh):
         full_logits, _ = jax.jit(model.prefill)(params, batch)
 
         short = dict(batch)
@@ -154,7 +154,7 @@ def test_decode_from_scratch(arch):
         model.cache_specs(b, max_len), jax.random.PRNGKey(0)
     )
     tok = jnp.ones((b, 1), jnp.int32)
-    with set_mesh_compat(CTX.mesh):
+    with jax.set_mesh(CTX.mesh):
         decode = jax.jit(model.decode_step)
         for _ in range(4):
             logits, cache = decode(params, cache, tok)

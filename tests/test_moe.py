@@ -17,7 +17,6 @@ import pytest
 from repro.models.common import init_params
 from repro.models.moe import MoeDims, moe_ffn, moe_param_specs, moe_reference
 from repro.sharding.rules import single_device_context
-from repro.sharding.rules import set_mesh_compat
 
 
 def _setup(key, t, d, f, e, k, ep, cf=8.0):
@@ -32,7 +31,7 @@ def test_single_device_matches_reference():
     t, d, f, e, k = 32, 16, 24, 6, 2
     dims, params = _setup(jax.random.PRNGKey(0), t, d, f, e, k, ep=1)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, d))
-    with set_mesh_compat(ctx.mesh):
+    with jax.set_mesh(ctx.mesh):
         y, aux, drop = jax.jit(
             lambda x, p: moe_ffn(
                 x,
@@ -57,7 +56,7 @@ def test_padded_experts_never_routed():
     dims, params = _setup(jax.random.PRNGKey(2), 16, 8, 12, 3, 2, ep=4)
     assert dims.n_experts_padded == 4
     x = jax.random.normal(jax.random.PRNGKey(3), (1, 16, 8))
-    with set_mesh_compat(ctx.mesh):
+    with jax.set_mesh(ctx.mesh):
         y, _, drop = moe_ffn(
             x, params, dims, mesh=ctx.mesh, dp_axes=ctx.dp_axes,
             ep_axis="model",
@@ -74,7 +73,7 @@ def test_capacity_drops_tokens():
         jax.random.PRNGKey(4), 64, 8, 12, 4, 2, ep=1, cf=0.25
     )
     x = jax.random.normal(jax.random.PRNGKey(5), (1, 64, 8))
-    with set_mesh_compat(ctx.mesh):
+    with jax.set_mesh(ctx.mesh):
         _, _, drop = moe_ffn(
             x, params, dims, mesh=ctx.mesh, dp_axes=ctx.dp_axes,
             ep_axis="model",
@@ -90,15 +89,15 @@ _MULTI_DEVICE_SCRIPT = textwrap.dedent(
     from repro.models.common import init_params
     from repro.models.moe import MoeDims, moe_ffn, moe_param_specs, moe_reference
     from repro.sharding.rules import MeshContext
-    from repro.sharding.rules import make_mesh_compat, set_mesh_compat
+    from repro.sharding.rules import make_auto_mesh
 
-    mesh = make_mesh_compat((2, 4), ("data", "model"))
+    mesh = make_auto_mesh((2, 4), ("data", "model"))
     ctx = MeshContext(mesh=mesh, dp_axes=("data",))
     d, f, e, k = 16, 24, 8, 2   # 8 experts over ep=4 -> 2 local experts
     dims = MoeDims.for_mesh(e, k, d, f, 4, capacity_factor=8.0)
     params = init_params(moe_param_specs(dims, False), jax.random.PRNGKey(0))
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, d))
-    with set_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         y, aux, drop = jax.jit(lambda x, p: moe_ffn(
             x, p, dims, mesh=mesh, dp_axes=("data",), ep_axis="model"
         ))(x, params)
@@ -108,7 +107,7 @@ _MULTI_DEVICE_SCRIPT = textwrap.dedent(
         np.asarray(y.reshape(-1, d)), np.asarray(ref), rtol=2e-4, atol=2e-4)
 
     # Token-sliced EP (Perf lever) must agree with the oracle too.
-    with set_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         y2, _, drop2 = jax.jit(lambda x, p: moe_ffn(
             x, p, dims, mesh=mesh, dp_axes=("data",), ep_axis="model",
             token_slice=True,
@@ -118,7 +117,7 @@ _MULTI_DEVICE_SCRIPT = textwrap.dedent(
         np.asarray(y2.reshape(-1, d)), np.asarray(ref), rtol=2e-4, atol=2e-4)
 
     # Sequence-sharded fused SP+EP path (seq dim 8 % ep 4 == 0).
-    with set_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         y3, _, _ = jax.jit(lambda x, p: moe_ffn(
             x, p, dims, mesh=mesh, dp_axes=("data",), ep_axis="model",
             token_slice=True, seq_sharded=True,

@@ -11,9 +11,9 @@ _SCRIPT = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from repro.train.pipeline import gpipe_forward, gpipe_loss_fn, stack_stages
-    from repro.sharding.rules import make_mesh_compat, set_mesh_compat
+    from repro.sharding.rules import make_auto_mesh
 
-    mesh = make_mesh_compat((4,), ("pipe",))
+    mesh = make_auto_mesh((4,), ("pipe",))
     L, D, M, MB = 8, 16, 6, 4   # 8 layers over 4 stages, 6 microbatches
 
     def layer_fn(w, h):
@@ -32,7 +32,7 @@ _SCRIPT = textwrap.dedent(
 
     ref = sequential(ws, x)
     staged = stack_stages(ws, 4)
-    with set_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         out = jax.jit(lambda p, x: gpipe_forward(
             p, x, mesh=mesh, axis="pipe", layer_fn=layer_fn))(staged, x)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -47,7 +47,7 @@ _SCRIPT = textwrap.dedent(
         return loss(sequential(ws, x), y)
 
     g_ref = jax.grad(seq_loss)(ws, x, y)
-    with set_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         g_pipe = jax.jit(jax.grad(lambda p, x, y: gpipe_loss_fn(
             p, x, y, mesh=mesh, axis="pipe",
             layer_fn=layer_fn, loss_fn=loss)))(staged, x, y)

@@ -7,12 +7,13 @@ from repro.configs.base import shape_cell
 from repro.configs.registry import get_config
 from repro.core.planner import profile_serve_step, profile_train_step
 from repro.models.lm import build_model
-from repro.sharding.rules import MeshContext, abstract_mesh_compat
+from jax.sharding import AbstractMesh
+from repro.sharding.rules import MeshContext
 
 
 def _ctx(shape=(16, 16), axes=("data", "model"), dp=("data",)):
     return MeshContext(
-        mesh=abstract_mesh_compat(shape, axes), dp_axes=dp
+        mesh=AbstractMesh(shape, axes), dp_axes=dp
     )
 
 

@@ -11,9 +11,9 @@ _SCRIPT = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from repro.models.attention import decode_attention, sharded_decode_attention
-    from repro.sharding.rules import make_mesh_compat, set_mesh_compat
+    from repro.sharding.rules import make_auto_mesh
 
-    mesh = make_mesh_compat((8,), ("data",))
+    mesh = make_auto_mesh((8,), ("data",))
     b, smax, hq, hkv, d = 2, 64, 4, 2, 16
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (b, 1, hq, d))
@@ -22,7 +22,7 @@ _SCRIPT = textwrap.dedent(
     lens = jnp.array([37, 64], jnp.int32)  # ragged validity
 
     ref = decode_attention(q, k, v, lens)
-    with set_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         out = jax.jit(lambda *a: sharded_decode_attention(
             *a, mesh=mesh, axis="data"))(q, k, v, lens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),

@@ -5,19 +5,19 @@ import math
 import jax
 from hypothesis import given, settings
 from hypothesis import strategies as st
+from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
 
 from repro.sharding.rules import (
     DEFAULT_RULES,
     MeshContext,
-    abstract_mesh_compat,
     fsdp_spec,
 )
 
 
 def _ctx(shape=(16, 16), axes=("data", "model"), dp=("data",)):
     return MeshContext(
-        mesh=abstract_mesh_compat(shape, axes), dp_axes=dp
+        mesh=AbstractMesh(shape, axes), dp_axes=dp
     )
 
 

@@ -298,10 +298,10 @@ _CONSISTENCY_SCRIPT = textwrap.dedent(
     from jax.sharding import PartitionSpec as P, NamedSharding
     from repro.configs.base import ShapeCell
     from repro.configs.registry import smoke_config
-    from repro.sharding.rules import make_mesh_compat, set_mesh_compat
+    from repro.sharding.rules import make_auto_mesh
     from repro.trace import hlo_trace, static_trace
 
-    mesh = make_mesh_compat((2, 4), ("data", "model"))
+    mesh = make_auto_mesh((2, 4), ("data", "model"))
     DP, TP = 2, 4
 
     for arch in ("gemma_2b", "qwen2_1_5b"):
@@ -321,7 +321,7 @@ _CONSISTENCY_SCRIPT = textwrap.dedent(
         )
         w1 = jax.ShapeDtypeStruct((cfg.d_model, cfg.d_ff), jnp.bfloat16)
         w2 = jax.ShapeDtypeStruct((cfg.d_ff, cfg.d_model), jnp.bfloat16)
-        with set_mesh_compat(mesh):
+        with jax.set_mesh(mesh):
             compiled = (
                 jax.jit(
                     block,

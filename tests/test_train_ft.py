@@ -13,7 +13,7 @@ from repro.configs.registry import smoke_config
 from repro.data.pipeline import SyntheticPipeline, shard_batch
 from repro.models.lm import build_model
 from repro.optim.adamw import AdamWConfig, adamw_init, adamw_update, lr_at
-from repro.sharding.rules import single_device_context, set_mesh_compat
+from repro.sharding.rules import single_device_context
 from repro.train.checkpoint import (
     latest_step,
     restore_checkpoint,
@@ -58,7 +58,7 @@ class TestLoop:
         params = init_train_state(model, jax.random.PRNGKey(0)).params
         pipe = SyntheticPipeline(model.cfg, CELL, seed=2)
         batch = shard_batch(next(pipe), CTX)
-        with set_mesh_compat(CTX.mesh):
+        with jax.set_mesh(CTX.mesh):
             l1, _, g1 = jax.jit(make_grad_fn(model, 1))(params, batch)
             l4, _, g4 = jax.jit(make_grad_fn(model, 4))(params, batch)
         np.testing.assert_allclose(float(l1), float(l4), rtol=1e-3)
@@ -168,7 +168,7 @@ class TestElastic:
         save_checkpoint(str(tmp_path), state, pipe.state())
         # "New" mesh: same devices, different context object; at scale
         # this is the (fewer-hosts) recovery mesh.
-        from repro.sharding.rules import single_device_context, set_mesh_compat
+        from repro.sharding.rules import single_device_context
 
         ctx2 = single_device_context()
         model2 = build_model(trainer.model.cfg, ctx2)
