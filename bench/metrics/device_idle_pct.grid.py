@@ -1,0 +1,7 @@
+"""Share of the traced grid-sweep window in which no operation ran on the
+device, in %: one minus the union of the device's operation intervals
+over the window."""
+
+
+def read(ctx):
+    return ctx.trace["idle_pct"]
