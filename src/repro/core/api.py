@@ -57,6 +57,7 @@ from repro.core.milp import solve_milp
 from repro.core.patterns import Pattern
 from repro.core.schedule import DependencyMode, Schedule
 from repro.core.simulator import execute
+from repro.obs.trace import host_span, request_scope
 
 if TYPE_CHECKING:
     from repro.core.ir.backends import TimingBackend
@@ -368,10 +369,11 @@ def _plan_grid(
         planner=opts.planner,
         attribution=opts.attribution,
     )
-    straw = batch_evaluate(
-        [strawman_instance(fabric, pattern) for fabric, pattern in cells],
-        backend=backend,
-    )
+    with host_span("repro.grid.baseline", cells=len(cells)):
+        baseline = [
+            strawman_instance(fabric, pattern) for fabric, pattern in cells
+        ]
+    straw = batch_evaluate(baseline, backend=backend)
     return tuple(
         GridCellPlan(plan=plan, strawman_cct=float(straw.cct[i]))
         for i, plan in enumerate(plans)
@@ -387,7 +389,15 @@ def plan(request: PlanRequest) -> PlanResult:
     are bitwise-identical to the legacy entry points these paths were
     lifted from (``swot_schedule`` / ``plan_grid``), which now delegate
     here.
+
+    Each call runs under its own ``request_scope``, so the host spans it
+    opens (`repro.obs.trace.host_span`) share one ``request`` number.
     """
+    with request_scope():
+        return _plan(request)
+
+
+def _plan(request: PlanRequest) -> PlanResult:
     opts = request.options
     if not request.is_batched:
         fabric, pattern = request.cells[0]

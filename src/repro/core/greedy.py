@@ -85,6 +85,7 @@ from repro.core.schedule import (
 from repro.core.simulator import execute
 from repro.core.tolerances import EPS as _EPS
 from repro.core.tolerances import EPS_VOLUME as _EPS_VOLUME
+from repro.obs.trace import host_span
 
 if TYPE_CHECKING:
     from repro.core.ir.backends import TimingBackend
@@ -1040,41 +1041,44 @@ def _chain_grid_decisions(
     if planner == "fused":
         from repro.core.ir.fused import fused_chain_grid_chosen
 
-        chosen = fused_chain_grid_chosen(st, rollout_horizon)
+        with host_span("repro.fused.call", cells=b):
+            chosen = fused_chain_grid_chosen(st, rollout_horizon)
     else:
-        chosen = _chain_grid_chosen(st, rollout_horizon)
+        with host_span("repro.grid.step_loop", cells=b):
+            chosen = _chain_grid_chosen(st, rollout_horizon)
 
-    splits: list[list[dict[int, float]]] = [[] for _ in range(b)]
-    bypass_steps: list[list[tuple[BypassRoute, ...]]] = [
-        [] for _ in range(b)
-    ]
-    for live_insts, split, byph in chosen:
-        for row, bi in enumerate(live_insts):
-            n_p = int(st.n_p[bi])
-            splits[bi].append(
-                {
-                    j: float(split[row, j])
-                    for j in range(n_p)
-                    if split[row, j] > 0.0 and byph[row, j] < 2
-                }
-            )
-            bypass_steps[bi].append(
-                tuple(
-                    BypassRoute(
-                        planes=(j,) * int(byph[row, j]),
-                        volume=float(split[row, j]),
-                    )
-                    for j in range(n_p)
-                    if split[row, j] > 0.0 and byph[row, j] >= 2
+    with host_span("repro.grid.materialize", cells=b):
+        splits: list[list[dict[int, float]]] = [[] for _ in range(b)]
+        bypass_steps: list[list[tuple[BypassRoute, ...]]] = [
+            [] for _ in range(b)
+        ]
+        for live_insts, split, byph in chosen:
+            for row, bi in enumerate(live_insts):
+                n_p = int(st.n_p[bi])
+                splits[bi].append(
+                    {
+                        j: float(split[row, j])
+                        for j in range(n_p)
+                        if split[row, j] > 0.0 and byph[row, j] < 2
+                    }
                 )
+                bypass_steps[bi].append(
+                    tuple(
+                        BypassRoute(
+                            planes=(j,) * int(byph[row, j]),
+                            volume=float(split[row, j]),
+                        )
+                        for j in range(n_p)
+                        if split[row, j] > 0.0 and byph[row, j] >= 2
+                    )
+                )
+        return [
+            Decisions(
+                tuple(s),
+                bypass=tuple(bp) if with_bypass else None,
             )
-    return [
-        Decisions(
-            tuple(s),
-            bypass=tuple(bp) if with_bypass else None,
-        )
-        for s, bp in zip(splits, bypass_steps)
-    ]
+            for s, bp in zip(splits, bypass_steps)
+        ]
 
 
 def _independent_grid_decisions(
@@ -1095,32 +1099,37 @@ def _independent_grid_decisions(
     if planner == "fused":
         from repro.core.ir.fused import fused_independent_grid_chosen
 
-        chosen = fused_independent_grid_chosen(st)
+        with host_span("repro.fused.call", cells=b):
+            chosen = fused_independent_grid_chosen(st)
     else:
-        for i in range(st.s_max):
-            live = i < st.n_s
-            if not live.any():
-                break
-            cfg_i = st.step_cfg[:, i][:, None]
-            extra = np.where(
-                st.config == cfg_i, 0.0, st.t_recfg[:, None]
-            )
-            finish = st.free + extra + st.step_vol[:, i][:, None] / st.bw
-            finish = np.where(st.real, finish, np.inf)
-            j = np.argmin(finish, axis=1)
-            rows = np.nonzero(live)[0]
-            jl = j[rows]
-            st.free[rows, jl] = finish[rows, jl]
-            st.config[rows, jl] = st.step_cfg[rows, i]
-            chosen.append((rows, jl, st.step_vol[rows, i]))
-    splits: list[list[dict[int, float]]] = [[] for _ in range(b)]
-    for rows, jl, vols in chosen:
-        for bi, j, v in zip(rows, jl, vols):
-            splits[bi].append({int(j): float(v)})
-    return [
-        Decisions(tuple(s), mode=DependencyMode.INDEPENDENT)
-        for s in splits
-    ]
+        with host_span("repro.grid.step_loop", cells=b):
+            for i in range(st.s_max):
+                live = i < st.n_s
+                if not live.any():
+                    break
+                cfg_i = st.step_cfg[:, i][:, None]
+                extra = np.where(
+                    st.config == cfg_i, 0.0, st.t_recfg[:, None]
+                )
+                finish = (
+                    st.free + extra + st.step_vol[:, i][:, None] / st.bw
+                )
+                finish = np.where(st.real, finish, np.inf)
+                j = np.argmin(finish, axis=1)
+                rows = np.nonzero(live)[0]
+                jl = j[rows]
+                st.free[rows, jl] = finish[rows, jl]
+                st.config[rows, jl] = st.step_cfg[rows, i]
+                chosen.append((rows, jl, st.step_vol[rows, i]))
+    with host_span("repro.grid.materialize", cells=b):
+        splits: list[list[dict[int, float]]] = [[] for _ in range(b)]
+        for rows, jl, vols in chosen:
+            for bi, j, v in zip(rows, jl, vols):
+                splits[bi].append({int(j): float(v)})
+        return [
+            Decisions(tuple(s), mode=DependencyMode.INDEPENDENT)
+            for s in splits
+        ]
 
 
 def _independent_split_grid_decisions(
@@ -1145,37 +1154,40 @@ def _independent_split_grid_decisions(
             fused_independent_split_grid_chosen,
         )
 
-        chosen = fused_independent_split_grid_chosen(st)
+        with host_span("repro.fused.call", cells=b):
+            chosen = fused_independent_split_grid_chosen(st)
     else:
-        for i in range(st.s_max):
-            live = i < st.n_s
-            if not live.any():
-                break
-            cfg_i = st.step_cfg[:, i][:, None]
-            extra = np.where(
-                st.config == cfg_i, 0.0, st.t_recfg[:, None]
-            )
-            ready = np.where(st.real, st.free + extra, _BIG)
-            vol_i = np.where(live, st.step_vol[:, i], 0.0)
-            level, split = waterfill_batch(ready, st.bw, vol_i)
-            active = (split > 0.0) & live[:, None]
-            st.free = np.where(active, level[:, None], st.free)
-            st.config = np.where(active, cfg_i, st.config)
-            chosen.append((np.nonzero(live)[0], split))
-    splits: list[list[dict[int, float]]] = [[] for _ in range(b)]
-    for rows, split in chosen:
-        for bi in rows:
-            splits[bi].append(
-                {
-                    j: float(split[bi, j])
-                    for j in range(int(st.n_p[bi]))
-                    if split[bi, j] > 0.0
-                }
-            )
-    return [
-        Decisions(tuple(s), mode=DependencyMode.INDEPENDENT)
-        for s in splits
-    ]
+        with host_span("repro.grid.step_loop", cells=b):
+            for i in range(st.s_max):
+                live = i < st.n_s
+                if not live.any():
+                    break
+                cfg_i = st.step_cfg[:, i][:, None]
+                extra = np.where(
+                    st.config == cfg_i, 0.0, st.t_recfg[:, None]
+                )
+                ready = np.where(st.real, st.free + extra, _BIG)
+                vol_i = np.where(live, st.step_vol[:, i], 0.0)
+                level, split = waterfill_batch(ready, st.bw, vol_i)
+                active = (split > 0.0) & live[:, None]
+                st.free = np.where(active, level[:, None], st.free)
+                st.config = np.where(active, cfg_i, st.config)
+                chosen.append((np.nonzero(live)[0], split))
+    with host_span("repro.grid.materialize", cells=b):
+        splits: list[list[dict[int, float]]] = [[] for _ in range(b)]
+        for rows, split in chosen:
+            for bi in rows:
+                splits[bi].append(
+                    {
+                        j: float(split[bi, j])
+                        for j in range(int(st.n_p[bi]))
+                        if split[bi, j] > 0.0
+                    }
+                )
+        return [
+            Decisions(tuple(s), mode=DependencyMode.INDEPENDENT)
+            for s in splits
+        ]
 
 
 def swot_greedy_grid(
@@ -1250,19 +1262,19 @@ def swot_greedy_grid(
         explicit=backend,
     )
     planner = select_planner_by_size(len(cells), explicit=planner)
-    st = _GridState(cells, mode=mode,
-                    max_enumerated_planes=max_enumerated_planes)
+    with host_span("repro.grid.state", cells=len(cells)):
+        st = _GridState(cells, mode=mode,
+                        max_enumerated_planes=max_enumerated_planes)
     if mode is DependencyMode.CHAIN:
         decisions = _chain_grid_decisions(st, rollout_horizon, planner)
-        st_byp = (
-            _GridState(
-                cells, mode=mode,
-                max_enumerated_planes=max_enumerated_planes,
-                bypass_depth=bypass_depth,
-            )
-            if bypass_depth >= 2
-            else None
-        )
+        st_byp = None
+        if bypass_depth >= 2:
+            with host_span("repro.grid.state", cells=len(cells)):
+                st_byp = _GridState(
+                    cells, mode=mode,
+                    max_enumerated_planes=max_enumerated_planes,
+                    bypass_depth=bypass_depth,
+                )
         # Mirror the per-instance `depth_tab.any()` guard: a grid with
         # no self-relay opportunity anywhere (e.g. all xor pairings)
         # skips the twin pass and its two scoring passes entirely.

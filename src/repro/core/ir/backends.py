@@ -303,6 +303,8 @@ def _build_jax_timing(attribution: bool = False) -> Callable:
     jax = _require_jax()
     import jax.numpy as jnp
 
+    # Compiled as ``jit_fn``: the program name the benchmark's
+    # ``timing_scan_ms`` reads (pinned by tests/test_trace_spans.py).
     def fn(
         vol, step_vol, step_cfg, step_mask, plane_mask, bw, init,
         t_recfg, chain, ready, byp_vol, byp_plane,

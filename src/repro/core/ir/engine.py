@@ -63,6 +63,7 @@ from repro.core.tolerances import (
     TOL,
     times_close_arr,
 )
+from repro.obs.trace import host_span
 
 if TYPE_CHECKING:
     from repro.core.ir.backends import TimingBackend
@@ -731,9 +732,13 @@ def batch_evaluate(
             volume_ok=np.ones(0, dtype=bool),
             attribution=att,
         )
-    return resolve_backend(backend).derive_timing(
-        pack_instances(instances, plane_ready), attribution=attribution
-    )
+    cells = len(instances)
+    with host_span("repro.ir.pack", cells=cells):
+        packed = pack_instances(instances, plane_ready)
+    with host_span("repro.ir.timing", cells=cells):
+        return resolve_backend(backend).derive_timing(
+            packed, attribution=attribution
+        )
 
 
 def evaluate_decisions(

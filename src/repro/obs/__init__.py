@@ -23,64 +23,63 @@ Three answers to "where did the time go":
   the metrics substrate.
 
 See DESIGN.md sections 16 and 20.
+
+Names are imported from their submodule on first use, so that importing
+``repro.obs.trace`` (which `repro.core` does, for ``host_span``) imports
+nothing of `repro.core`.
 """
 
-from repro.obs.attribution import (
-    Attribution,
-    attribute,
-    build_attribution,
-    closing_idle,
-    component_sum,
-    step_barriers,
-)
-from repro.obs.log import ENV_LOG, ObsLogger, get_logger
-from repro.obs.metrics import (
-    NULL_REGISTRY,
-    Counter,
-    Gauge,
-    Histogram,
-    MetricsRegistry,
-    NullRegistry,
-    validate_prometheus_text,
-)
-from repro.obs.slo import SLOMonitor, SLOTarget, TenantSLO
-from repro.obs.trace import (
-    JOBS_LANE,
-    NULL_TRACER,
-    ChromeTracer,
-    NullTracer,
-    Tracer,
-    trace_schedule,
-    validate_trace,
-    validate_trace_file,
-)
+from __future__ import annotations
 
-__all__ = [
-    "Attribution",
-    "ChromeTracer",
-    "Counter",
-    "ENV_LOG",
-    "Gauge",
-    "Histogram",
-    "JOBS_LANE",
-    "MetricsRegistry",
-    "NULL_REGISTRY",
-    "NULL_TRACER",
-    "NullRegistry",
-    "NullTracer",
-    "ObsLogger",
-    "SLOMonitor",
-    "SLOTarget",
-    "TenantSLO",
-    "Tracer",
-    "attribute",
-    "build_attribution",
-    "closing_idle",
-    "component_sum",
-    "get_logger",
-    "step_barriers",
-    "trace_schedule",
-    "validate_prometheus_text",
-    "validate_trace",
-    "validate_trace_file",
-]
+import importlib
+
+_EXPORTS = {
+    "attribution": (
+        "Attribution",
+        "attribute",
+        "build_attribution",
+        "closing_idle",
+        "component_sum",
+        "step_barriers",
+    ),
+    "log": ("ENV_LOG", "ObsLogger", "get_logger"),
+    "metrics": (
+        "NULL_REGISTRY",
+        "Counter",
+        "Gauge",
+        "Histogram",
+        "MetricsRegistry",
+        "NullRegistry",
+        "validate_prometheus_text",
+    ),
+    "slo": ("SLOMonitor", "SLOTarget", "TenantSLO"),
+    "trace": (
+        "JOBS_LANE",
+        "NULL_TRACER",
+        "ChromeTracer",
+        "NullTracer",
+        "Tracer",
+        "host_span",
+        "request_scope",
+        "trace_schedule",
+        "validate_trace",
+        "validate_trace_file",
+    ),
+}
+_MODULE_OF = {name: mod for mod, names in _EXPORTS.items() for name in names}
+
+__all__ = sorted(_MODULE_OF)
+
+
+def __getattr__(name: str):
+    if name not in _MODULE_OF:
+        raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+    value = getattr(
+        importlib.import_module(f"{__name__}.{_MODULE_OF[name]}"), name
+    )
+    globals()[name] = value
+    return value
+
+
+def __dir__() -> list[str]:
+    return sorted(set(globals()) | set(__all__))

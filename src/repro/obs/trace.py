@@ -24,12 +24,30 @@ Simulated seconds become microsecond timestamps.
 share: it verifies the exported payload is structurally a trace-event
 file (required keys per phase type, numeric timestamps, known lanes)
 without depending on Perfetto.
+
+Two clocks.  The ``Tracer`` family records *simulated* fabric seconds:
+when a plane reconfigures or transmits in the plan, whatever wall time
+computing the plan took.  ``host_span(name, **args)`` records *wall-clock*
+host time of the planner's own code: it writes a
+``jax.profiler.TraceAnnotation`` into the JAX profiler's trace, where it
+lands on the profiler's clock beside the device's XLA ops, so an idle
+gap on the device is named by the host phase that covers it.  Use a
+``Tracer`` to see what a plan does on the fabric; use ``host_span``
+(captured with ``jax.profiler.trace(dir)`` around ``plan()``) to see
+where planning spends its time.  ``host_span`` never imports ``jax``:
+where nothing has imported it, no profiler can be running and the span
+is a shared null context.  Spans opened under ``request_scope()`` (every
+``repro.core.api.plan`` call) carry that call's ``request=<n>`` argument.
 """
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
+import itertools
 import json
-from typing import Any
+import sys
+from typing import Any, Iterator
 
 # Lane (Chrome "thread") ids that are not plane indices.
 JOBS_LANE = 1000
@@ -65,6 +83,42 @@ class NullTracer(Tracer):
 
 
 NULL_TRACER = NullTracer()
+
+
+# -- wall-clock host spans (JAX profiler trace) ----------------------------
+_NULL_SPAN = contextlib.nullcontext()
+_REQUESTS = itertools.count(1)
+_REQUEST: contextvars.ContextVar[int | None] = contextvars.ContextVar(
+    "repro_request", default=None
+)
+
+
+@contextlib.contextmanager
+def request_scope() -> Iterator[int]:
+    """Number one planning call: every ``host_span`` opened inside the
+    block carries ``request=<n>``, ``n`` unique in the process."""
+    token = _REQUEST.set(next(_REQUESTS))
+    try:
+        yield _REQUEST.get()
+    finally:
+        _REQUEST.reset(token)
+
+
+def host_span(name: str, **args: Any):
+    """A wall-clock span of host code in the JAX profiler's trace.
+
+    ``jax.profiler.TraceAnnotation(name, **args)`` where ``jax`` is
+    already imported, with the enclosing ``request_scope``'s number added
+    as ``request``; else a shared null context.  With the profiler off
+    the annotation costs about a microsecond.
+    """
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return _NULL_SPAN
+    request = _REQUEST.get()
+    if request is not None:
+        args["request"] = request
+    return jax.profiler.TraceAnnotation(name, **args)
 
 
 class ChromeTracer(Tracer):
