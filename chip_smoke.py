@@ -131,8 +131,8 @@ class _PathProbe:
         build, backend = fused._chain_tables, get_backend("jax")
         derive = backend.derive_timing
 
-        def tables(st, with_bypass):
-            tab = build(st, with_bypass)
+        def tables(st, *args):
+            tab = build(st, *args)
             self.table_platforms.append(
                 {d.platform for a in tab.values() for d in a.devices()}
             )

@@ -70,10 +70,13 @@ from repro.core.ir import (
     waterfill_batch,
 )
 from repro.core.ir.backends import (
+    DEFAULT_FUSED_PLANNER_THRESHOLD,
     DEFAULT_GRID_BACKEND_THRESHOLD,
     ENV_GRID_BACKEND_THRESHOLD,
+    TPU_CHAIN_FUSED_PLANNER_THRESHOLD,
     select_backend_by_size,
     select_planner_by_size,
+    tpu_chain_rule,
 )
 from repro.core.patterns import Pattern
 from repro.core.schedule import (
@@ -450,7 +453,10 @@ def swot_greedy_chain_batch(
     one timestamp become one grid, their reserve-set decisions advance
     through the per-step loop together (``_chain_grid_chosen``, or the
     fused ``lax.scan`` planner once the batch crosses
-    ``REPRO_FUSED_PLANNER_THRESHOLD``), and each cell is then
+    ``REPRO_FUSED_PLANNER_THRESHOLD``, default
+    ``DEFAULT_FUSED_PLANNER_THRESHOLD`` on every backend: this path has
+    no chip measurement, so the TPU rule of CHAIN sweep grids and its
+    cell buckets do not apply), and each cell is then
     materialized + polished exactly as ``swot_greedy_chain(polish=True)``
     would.  Because grid decisions are bitwise-identical to the
     per-instance greedy (the property the grid planners are pinned to),
@@ -1034,15 +1040,21 @@ def _chain_grid_decisions(
     (`_chain_grid_chosen`); ``"fused"`` runs the whole loop as one jitted
     ``lax.scan`` on device (`repro.core.ir.fused`) -- bitwise-identical
     chosen splits by contract (property-tested), so the materialization
-    below is shared verbatim.
+    below is shared verbatim.  The fused scan runs at
+    `fused.chain_cells_padded` cells (a power-of-two bucket under the
+    chip rule).
     """
     b = len(st.cells)
     with_bypass = st.bypass_depth >= 2
     if planner == "fused":
-        from repro.core.ir.fused import fused_chain_grid_chosen
+        from repro.core.ir.fused import (
+            chain_cells_padded,
+            fused_chain_grid_chosen,
+        )
 
-        with host_span("repro.fused.call", cells=b):
-            chosen = fused_chain_grid_chosen(st, rollout_horizon)
+        b_pad = chain_cells_padded(b)
+        with host_span("repro.fused.call", cells=b, padded=b_pad):
+            chosen = fused_chain_grid_chosen(st, rollout_horizon, b_pad)
     else:
         with host_span("repro.grid.step_loop", cells=b):
             chosen = _chain_grid_chosen(st, rollout_horizon)
@@ -1235,8 +1247,12 @@ def swot_greedy_grid(
     loop as ONE jitted ``lax.scan`` device program,
     `repro.core.ir.fused` -- bitwise-identical decisions by contract),
     or ``None`` to auto-select fused once the grid reaches
-    ``REPRO_FUSED_PLANNER_THRESHOLD`` cells
-    (`select_planner_by_size`).
+    ``REPRO_FUSED_PLANNER_THRESHOLD`` cells (`select_planner_by_size`).
+    Without the variable a grid takes fused from
+    ``DEFAULT_FUSED_PLANNER_THRESHOLD`` cells (set on the CPU), except a
+    smaller CHAIN grid on a TPU, which takes it from
+    ``TPU_CHAIN_FUSED_PLANNER_THRESHOLD`` (measured on one TPU v5e) with
+    the scan's cell axis padded to a power of two, one compile a bucket.
 
     ``attribution=True`` threads the CCT decomposition through the final
     scoring pass: each returned ``GridPlan.attribution`` carries its
@@ -1261,7 +1277,15 @@ def swot_greedy_grid(
         DEFAULT_GRID_BACKEND_THRESHOLD,
         explicit=backend,
     )
-    planner = select_planner_by_size(len(cells), explicit=planner)
+    planner = select_planner_by_size(
+        len(cells),
+        explicit=planner,
+        default_threshold=(
+            TPU_CHAIN_FUSED_PLANNER_THRESHOLD
+            if mode is DependencyMode.CHAIN and tpu_chain_rule(len(cells))
+            else DEFAULT_FUSED_PLANNER_THRESHOLD
+        ),
+    )
     with host_span("repro.grid.state", cells=len(cells)):
         st = _GridState(cells, mode=mode,
                         max_enumerated_planes=max_enumerated_planes)

@@ -13,8 +13,8 @@ The package splits the pre-refactor ``repro.core.ir`` module in two:
 * `repro.core.ir.fused`    -- the fused on-device grid planner: the
   whole per-step greedy loop (`repro.core.greedy.swot_greedy_grid`) as
   one jitted ``lax.scan``, bitwise-identical to the per-step numpy
-  planner.  Auto-selected above ``REPRO_FUSED_PLANNER_THRESHOLD``
-  cells (`select_planner_by_size`).
+  planner.  Auto-selected by grid size, mode and device
+  (`select_planner_by_size`).
 
 Every pre-refactor import (``from repro.core.ir import batch_evaluate``)
 keeps working; ``batch_evaluate``/``evaluate_decisions`` gained a

@@ -33,6 +33,7 @@ results stay deterministic unless an accelerator path is asked for.
 
 from __future__ import annotations
 
+import sys
 from typing import Callable
 
 import numpy as np
@@ -655,30 +656,63 @@ def select_backend_by_size(
     return "jax"
 
 
-# Grid-cell count at and above which ``swot_greedy_grid`` / ``plan_grid``
-# auto-select the FUSED on-device planner (`repro.core.ir.fused`): the
-# whole per-step greedy loop as one jitted lax.scan.  Below it the
-# per-step numpy loop wins (trace+compile does not amortize).  Where the
-# device's float64 is IEEE (the CPU) the two are bitwise-identical, so
-# there the threshold is purely a performance knob.  On a TPU, whose
-# float64 is emulated, the fused planner takes other near-tie decisions,
-# so a cell's plan depends on whether its grid crossed the threshold
-# (ROADMAP speed item 3).
-# Override with the env var; <= 0 disables fused auto-selection.
+# Grid-cell count at and above which the grid planners auto-select the
+# FUSED on-device planner (`repro.core.ir.fused`): the whole per-step
+# greedy loop as one jitted lax.scan.  Two defaults:
+#
+# * CHAIN sweep grids (``swot_greedy_grid`` / ``plan_grid``) of fewer
+#   than DEFAULT_FUSED_PLANNER_THRESHOLD cells on a TPU switch at
+#   TPU_CHAIN_FUSED_PLANNER_THRESHOLD, measured on one TPU v5e with the
+#   program loaded (`benchmarks/planner_crossover.py`, PERF.md): there
+#   the numpy loop's few thousand small host dispatches a grid cost more
+#   than the scan (`tpu_chain_rule`).  Under the same rule a fused CHAIN
+#   call pads the scan's cell axis to a power of two
+#   (`fused.chain_cells_padded`), so each bucket compiles once; the first
+#   grid of a bucket in a process pays that compile or a cache read.
+# * Every other grid keeps DEFAULT_FUSED_PLANNER_THRESHOLD, set on the
+#   CPU, unpadded: every other backend, INDEPENDENT grids, CHAIN grids of
+#   DEFAULT_FUSED_PLANNER_THRESHOLD cells or more, and the arbiter's
+#   batched grants (``swot_greedy_chain_batch``, which runs on the event
+#   path and has no chip cell yet).
+#
+# Where the device's float64 is IEEE (the CPU) the two planners are
+# bitwise-identical, so there the threshold is purely a performance
+# knob.  On a TPU, whose float64 is emulated, the fused planner's split
+# volumes differ in their last bits and rare near-tie choices flip, so a
+# cell's plan depends on which planner its grid took (ROADMAP speed
+# item 3).
+# The env var overrides both defaults; <= 0 disables fused auto-selection.
 ENV_FUSED_PLANNER_THRESHOLD = knobs.ENV_FUSED_PLANNER_THRESHOLD
 DEFAULT_FUSED_PLANNER_THRESHOLD = knobs.DEFAULT_FUSED_PLANNER_THRESHOLD
+TPU_CHAIN_FUSED_PLANNER_THRESHOLD = knobs.TPU_CHAIN_FUSED_PLANNER_THRESHOLD
+
+
+def tpu_chain_rule(n_cells: int) -> bool:
+    """Whether a CHAIN grid of ``n_cells`` falls under the chip's rule:
+    fewer than DEFAULT_FUSED_PLANNER_THRESHOLD cells, and this process's
+    JAX runs on a TPU.
+
+    False without importing JAX where nothing has imported it yet, so a
+    plan that stays on numpy never starts the XLA runtime for this.
+    """
+    if n_cells >= DEFAULT_FUSED_PLANNER_THRESHOLD:
+        return False
+    jax = sys.modules.get("jax")
+    return jax is not None and jax.default_backend() == "tpu"
 
 
 def select_planner_by_size(
-    n_cells: int, explicit: str | None = None
+    n_cells: int,
+    explicit: str | None = None,
+    default_threshold: int = DEFAULT_FUSED_PLANNER_THRESHOLD,
 ) -> str:
     """Threshold policy for the grid planner implementation.
 
     Returns ``"fused"`` (one-program ``lax.scan`` planner) once the grid
-    reaches ``REPRO_FUSED_PLANNER_THRESHOLD`` cells (default
-    ``DEFAULT_FUSED_PLANNER_THRESHOLD``) and jax is importable, else
-    ``"step"`` (the per-step numpy loop).  An ``explicit`` planner always
-    wins; a threshold <= 0 disables auto-selection.
+    reaches ``REPRO_FUSED_PLANNER_THRESHOLD`` cells (falling back to
+    ``default_threshold``) and jax is importable, else ``"step"`` (the
+    per-step numpy loop).  An ``explicit`` planner always wins; a
+    threshold <= 0 disables auto-selection.
     """
     if explicit is not None:
         if explicit not in ("step", "fused"):
@@ -686,7 +720,7 @@ def select_planner_by_size(
                 f"unknown planner {explicit!r}; choose 'step' or 'fused'"
             )
         return explicit
-    threshold = knobs.fused_planner_threshold()
+    threshold = knobs.fused_planner_threshold(default_threshold)
     if threshold <= 0 or n_cells < threshold:
         return "step"
     try:

@@ -55,8 +55,8 @@ from typing import TYPE_CHECKING
 
 import numpy as np
 
-from repro.core.ir.backends import x64
-from repro.core.ir.engine import _BIG
+from repro.core.ir.backends import _bucket, tpu_chain_rule, x64
+from repro.core.ir.engine import _BIG, NO_CONFIG
 from repro.core.tolerances import EPS as _EPS
 from repro.core.tolerances import EPS_VOLUME as _EPS_VOLUME
 
@@ -256,8 +256,6 @@ def _upcoming_targets_j(step_cfg, prev_same, n_s, config, scfg, i, p_max):
     unique per instance: first occurrences of distinct configs).
     """
     import jax.numpy as jnp
-
-    from repro.core.ir.engine import NO_CONFIG
 
     s_max = step_cfg.shape[1]
     s = i + 1
@@ -569,7 +567,9 @@ def _build_independent_scan(split_mode: bool):
 
 
 # jit-wrapped scan programs keyed by (kind, horizon, with_bypass); jax's
-# own jit cache handles the per-shape specialization underneath.
+# own jit cache handles the per-shape specialization underneath, once a
+# power-of-two bucket of cells where the CHAIN scan is bucketed
+# (`_pad_cells`).
 _SCAN_CACHE: dict[tuple, object] = {}
 
 
@@ -587,37 +587,111 @@ def _independent_scan(split_mode: bool):
     return _SCAN_CACHE[key]
 
 
+# The planner tables every fused scan reads, with their device dtypes
+# (`_GridState` attributes; cell axis first) ...
+_BASE_DTYPES = {
+    "n_p": np.int64, "n_s": np.int64, "bw": np.float64, "real": bool,
+    "config": np.int64, "free": np.float64, "installed": np.int64,
+    "step_cfg": np.int64, "step_vol": np.float64, "t_recfg": np.float64,
+}
+# ... and those only the CHAIN scan reads (the last two: one row a
+# candidate reserve set, not a cell).
+_CHAIN_DTYPES = {
+    **_BASE_DTYPES,
+    "bw_sum": np.float64, "suffix_vol": np.float64,
+    "suffix_changes": np.int64, "prev_same": np.int64,
+    "cand_mask": bool, "cand_inst": np.int64,
+}
+
+
+def _host_tables(st: "_GridState", dtypes: dict) -> dict:
+    return {key: np.asarray(getattr(st, key), t) for key, t in dtypes.items()}
+
+
+def _on_device(tab: dict) -> dict:
+    import jax.numpy as jnp
+
+    return {key: jnp.asarray(a) for key, a in tab.items()}
+
+
 def _base_tables(st: "_GridState") -> dict:
     """The shape-static planner tables, as device arrays (float64/int64)."""
-    import jax.numpy as jnp
+    return _on_device(_host_tables(st, _BASE_DTYPES))
 
-    return {
-        "n_p": jnp.asarray(st.n_p, jnp.int64),
-        "n_s": jnp.asarray(st.n_s, jnp.int64),
-        "bw": jnp.asarray(st.bw, jnp.float64),
-        "real": jnp.asarray(st.real, bool),
-        "config": jnp.asarray(st.config, jnp.int64),
-        "free": jnp.asarray(st.free, jnp.float64),
-        "installed": jnp.asarray(st.installed, jnp.int64),
-        "step_cfg": jnp.asarray(st.step_cfg, jnp.int64),
-        "step_vol": jnp.asarray(st.step_vol, jnp.float64),
-        "t_recfg": jnp.asarray(st.t_recfg, jnp.float64),
+
+def chain_cells_padded(n_cells: int) -> int:
+    """The cell count the fused CHAIN scan runs a grid of ``n_cells`` at.
+
+    A grid under the chip's rule (`repro.core.ir.backends.tpu_chain_rule`:
+    under ``DEFAULT_FUSED_PLANNER_THRESHOLD`` cells on a TPU) is padded to
+    the next power of two, the rule the jax timing backend applies to its
+    batch axis (`repro.core.ir.backends._bucket`): grids of one bucket
+    share one compiled program.  Everywhere else a grid keeps its size.
+    Steps and planes are not padded; the pattern sets them, and they take
+    few distinct values.
+    """
+    return _bucket(n_cells) if tpu_chain_rule(n_cells) else n_cells
+
+
+# Tables indexed by candidate row, not by cell.
+_ROW_KEYS = ("cand_mask", "cand_inst", "dyn_row", "dyn_size")
+# A padded cell's entries where zero is not inert: no config installed
+# or asked for, no earlier step of the same config, every plane present
+# (n_p) with finite nonzero bandwidth (the rollout tail divides by
+# ``bw_sum`` and ``n_p``).
+_PAD_FILL = {
+    "config": NO_CONFIG, "installed": NO_CONFIG, "step_cfg": NO_CONFIG,
+    "prev_same": -1, "bw": 1.0, "bw_sum": 1.0,
+}
+
+
+def _pad_cells(tab: dict, cand_start: np.ndarray, b_pad: int) -> dict:
+    """Append inert cells to the CHAIN tables' cell axis, up to ``b_pad``.
+
+    A padded cell is never live (``n_s = 0``) and has no real plane
+    (`_PAD_FILL` for the rest).  It gets as many candidate rows as the
+    grid's largest block, each reserving every plane, so ``valid``
+    (``size != n_p``) rejects them all.  The real rows keep their order,
+    so the row-id tie-break selects as before, and every reduction over
+    rows is per cell, so a padded cell touches no real one.
+    """
+    b = tab["n_s"].shape[0]
+    if b_pad == b:
+        return tab
+    pad = b_pad - b
+    p_max = tab["bw"].shape[1]
+    fill = {**_PAD_FILL, "n_p": p_max}
+    out = {
+        key: np.concatenate(
+            [a, np.full((pad,) + a.shape[1:], fill.get(key, 0), a.dtype)]
+        )
+        for key, a in tab.items()
+        if key not in _ROW_KEYS
     }
-
-
-def _chain_tables(st: "_GridState", with_bypass: bool) -> dict:
-    """Every table the fused CHAIN scan reads (call under `x64`)."""
-    import jax.numpy as jnp
-
-    tab = _base_tables(st)
-    tab.update(
-        bw_sum=jnp.asarray(st.bw_sum, jnp.float64),
-        suffix_vol=jnp.asarray(st.suffix_vol, jnp.float64),
-        suffix_changes=jnp.asarray(st.suffix_changes, jnp.int64),
-        prev_same=jnp.asarray(st.prev_same, jnp.int64),
-        cand_mask=jnp.asarray(st.cand_mask, bool),
-        cand_inst=jnp.asarray(st.cand_inst, jnp.int64),
+    n_rows = tab["cand_inst"].shape[0]
+    block = int(np.diff(np.r_[cand_start, n_rows]).max())
+    rows = pad * block
+    out.update(
+        cand_mask=np.concatenate(
+            [tab["cand_mask"], np.ones((rows, p_max), bool)]
+        ),
+        cand_inst=np.concatenate(
+            [tab["cand_inst"], np.repeat(np.arange(b, b_pad), block)]
+        ),
+        dyn_row=np.concatenate([tab["dyn_row"], np.zeros(rows, bool)]),
+        dyn_size=np.concatenate(
+            [tab["dyn_size"], np.full(rows, -1, np.int64)]
+        ),
     )
+    return out
+
+
+def _chain_tables(
+    st: "_GridState", with_bypass: bool, b_pad: int | None = None
+) -> dict:
+    """Every table the fused CHAIN scan reads (call under `x64`), the cell
+    axis padded to ``b_pad`` cells where given (`_pad_cells`)."""
+    tab = _host_tables(st, _CHAIN_DTYPES)
     # Dynamic rows: soonest-free prefixes of sizes 0..3, refreshed per
     # step on device.  `dyn_size` holds the prefix size per dynamic row
     # (-1 for static rows, which never match a rank).
@@ -627,35 +701,36 @@ def _chain_tables(st: "_GridState", with_bypass: bool) -> dict:
         start = int(st.cand_start[bi])
         dyn_row[start:start + 4] = True
         dyn_size[start:start + 4] = np.arange(4)
-    tab.update(
-        dyn_row=jnp.asarray(dyn_row),
-        dyn_size=jnp.asarray(dyn_size),
-    )
+    tab.update(dyn_row=dyn_row, dyn_size=dyn_size)
     if with_bypass:
-        tab["depth_tab"] = jnp.asarray(st.depth_tab, jnp.int64)
-    return tab
+        tab["depth_tab"] = np.asarray(st.depth_tab, np.int64)
+    if b_pad is not None:
+        tab = _pad_cells(tab, st.cand_start, b_pad)
+    return _on_device(tab)
 
 
 def fused_chain_grid_chosen(
-    st: "_GridState", rollout_horizon: int
+    st: "_GridState", rollout_horizon: int, b_pad: int | None = None
 ) -> list[tuple[np.ndarray, np.ndarray, np.ndarray]]:
     """Plan every CHAIN step of the grid in one device program.
 
     Returns the same per-step ``(live_insts, split, byp_h)`` tuples the
     numpy loop (`greedy._chain_grid_decisions`) accumulates -- bitwise
     identical -- for the shared Decisions materialization epilogue.
-    Raises the same "no feasible reserve set" assertion on infeasible
-    steps.
+    ``b_pad`` pads the scan's cell axis (`chain_cells_padded`); the
+    padded cells' outputs are cut off first.  Raises the same
+    "no feasible reserve set" assertion on infeasible steps.
     """
     _require_jax()
+    b = len(st.cells)
     with_bypass = st.bypass_depth >= 2 and st.depth_tab.shape[1] > 0
     with x64():
         ys = _chain_scan(rollout_horizon, with_bypass)(
-            _chain_tables(st, with_bypass)
+            _chain_tables(st, with_bypass, b_pad)
         )
-        split_s = np.asarray(ys[0], dtype=np.float64)
-        byph_s = np.asarray(ys[1], dtype=np.int64)
-        feas_s = np.asarray(ys[2], dtype=bool)
+        split_s = np.asarray(ys[0], dtype=np.float64)[:, :b]
+        byph_s = np.asarray(ys[1], dtype=np.int64)[:, :b]
+        feas_s = np.asarray(ys[2], dtype=bool)[:, :b]
     chosen = []
     for i in range(st.s_max):
         live = i < st.n_s

@@ -37,7 +37,17 @@ DEFAULT_IR_BACKEND = "numpy"
 # asserts the invariant at import.
 DEFAULT_ARBITER_BACKEND_THRESHOLD = 16
 DEFAULT_GRID_BACKEND_THRESHOLD = 64
+# Set on the CPU, where below it the fused scan's trace and compile did
+# not pay for themselves; every grid keeps it but the CHAIN sweep grids
+# below it on a TPU.
 DEFAULT_FUSED_PLANNER_THRESHOLD = 256
+# Those grids: on one TPU v5e the fused scan (program loaded) beat the
+# per-step numpy loop about 10x at every size measured, from one cell
+# (benchmarks/planner_crossover.py, PERF.md), so every such grid takes
+# it.  The first grid of a power-of-two bucket in a process pays a
+# compile or a cache read instead.  Not an environment knob;
+# REPRO_FUSED_PLANNER_THRESHOLD overrides it too.
+TPU_CHAIN_FUSED_PLANNER_THRESHOLD = 1
 DEFAULT_LOG = ""  # "" = plain narrative rendering
 
 
@@ -101,7 +111,9 @@ KNOBS: dict[str, Knob] = {
             "int",
             DEFAULT_FUSED_PLANNER_THRESHOLD,
             "grid-cell count at which the fused lax.scan planner is "
-            "auto-selected (<= 0 disables)",
+            "auto-selected, on every grid (unset: "
+            f"{DEFAULT_FUSED_PLANNER_THRESHOLD}, smaller CHAIN sweep grids "
+            f"on a TPU {TPU_CHAIN_FUSED_PLANNER_THRESHOLD}; <= 0 disables)",
         ),
         Knob(
             ENV_LOG,
@@ -128,8 +140,11 @@ def grid_backend_threshold() -> int:
     return KNOBS[ENV_GRID_BACKEND_THRESHOLD].value()
 
 
-def fused_planner_threshold() -> int:
-    return KNOBS[ENV_FUSED_PLANNER_THRESHOLD].value()
+def fused_planner_threshold(
+    default: int = DEFAULT_FUSED_PLANNER_THRESHOLD,
+) -> int:
+    """``REPRO_FUSED_PLANNER_THRESHOLD`` where set, else ``default``."""
+    return int_knob(ENV_FUSED_PLANNER_THRESHOLD, default)
 
 
 def log_mode() -> str:

@@ -163,8 +163,8 @@ def plan_grid(
     ``planner`` picks the loop implementation: ``"step"`` (per-step
     numpy), ``"fused"`` (the whole loop as one jitted ``lax.scan`` on
     device, `repro.core.ir.fused` -- bitwise-identical decisions), or
-    ``None`` to auto-select fused at ``REPRO_FUSED_PLANNER_THRESHOLD``
-    cells.  ``attribution=True`` threads the per-cell CCT decomposition
+    ``None`` to auto-select by grid size, mode and device
+    (`select_planner_by_size`).  ``attribution=True`` threads the per-cell CCT decomposition
     (`repro.obs.attribution.Attribution`) through the scoring pass onto
     each ``GridCellPlan.plan.attribution`` -- composes with both
     planners and every backend.

@@ -9,7 +9,14 @@ Contract under test (`repro.core.ir.fused` + the ``planner=`` plumbing):
   (no numpy delegation) with bitwise CCT/attribution parity across
   padding shapes, and padded cells never leak into real cells;
 * ``attribution=True`` composes with the fused planner;
-* ``select_planner_by_size`` honors threshold / env / explicit choice;
+* where the CHAIN scan's cell axis is padded to a power of two (CHAIN
+  grids under the default threshold on a TPU), decisions stay
+  bitwise-equal to the step loop's, padded instances never reach the
+  per-step tuples, and grids of one bucket share one compiled program;
+  elsewhere each grid keeps its own shape;
+* ``select_planner_by_size`` honors threshold / env / explicit choice,
+  only CHAIN sweep grids under the default threshold on a TPU take the
+  chip's threshold, and a grid planned on numpy never imports jax;
 * the fused planner's numeric primitives (`_no_fma` FMA guard, the
   odd-even sorting network, pairwise stable ranks, the column-wise
   water-fill) match their numpy references bitwise -- eager AND jitted,
@@ -19,17 +26,24 @@ Run with ``JAX_PLATFORMS=cpu`` in CI so these legs exercise the exact
 code path a CPU-only host gets.
 """
 
+import os
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
 from repro.core import BatchInstance, OpticalFabric, batch_evaluate
+from repro.core import greedy
 from repro.core.greedy import _GridState, swot_greedy_grid
 from repro.core.ir.backends import (
     BackendUnavailable,
     DEFAULT_FUSED_PLANNER_THRESHOLD,
     ENV_FUSED_PLANNER_THRESHOLD,
+    TPU_CHAIN_FUSED_PLANNER_THRESHOLD,
+    _bucket,
     get_backend,
     select_planner_by_size,
     x64,
@@ -136,6 +150,86 @@ class TestFusedChainParity:
 
 
 # ---------------------------------------------------------------------------
+# The CHAIN scan's cell axis is padded to a power of two
+# ---------------------------------------------------------------------------
+def _sweep(n_cells: int, planes=(4,)) -> list:
+    """``n_cells`` 8-node cells over sizes, delays and ``planes``."""
+    rng = np.random.default_rng(n_cells)
+    cells = []
+    for i in range(n_cells):
+        maker = (pairwise_alltoall, rabenseifner_allreduce)[i % 2]
+        pattern = maker(8, float(rng.uniform(1e5, 2e8)))
+        fabric = OpticalFabric(
+            8, planes[i % len(planes)],
+            t_recfg=float(rng.choice([0.0, 50e-6, 200e-6, 3.2e-3])),
+        )
+        if i % 5 == 0:
+            fabric = fabric.prestaged(pattern.steps[0].config)
+        cells.append((fabric, pattern))
+    return cells
+
+
+class TestBucketedChain:
+    @pytest.mark.parametrize(
+        "n_cells, planes, bypass, enum_planes",
+        [
+            (3, (4, 2, 3), 0, 8),
+            (70, (4,), 0, 2),
+            (100, (2, 4, 3), 2, 8),
+        ],
+    )
+    def test_parity_with_step_loop(self, n_cells, planes, bypass,
+                                   enum_planes, monkeypatch):
+        # A TPU as the chip rule sees it: the fused scan is bucketed.
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        cells = _sweep(n_cells, planes)
+        kw = dict(bypass_depth=bypass, max_enumerated_planes=enum_planes)
+        step = swot_greedy_grid(cells, planner="step", **kw)
+        fus = swot_greedy_grid(cells, planner="fused", **kw)
+        _assert_same_plans(step, fus)
+
+    @pytest.mark.parametrize("n_cells", [3, 70])
+    def test_padded_instances_never_reach_the_tuples(self, n_cells):
+        cells = _sweep(n_cells, (4, 3))
+        b_pad = _bucket(n_cells)
+        assert b_pad > n_cells
+        with x64():
+            tab = fused._chain_tables(_GridState(cells), False, b_pad)
+        assert tab["n_s"].shape == (b_pad,)
+        fus = fused.fused_chain_grid_chosen(_GridState(cells), 24, b_pad)
+        ref = greedy._chain_grid_chosen(_GridState(cells), 24)
+        assert len(fus) == len(ref)
+        for (rows, split, byph), (r_rows, r_split, r_byph) in zip(fus, ref):
+            assert rows.max() < n_cells
+            assert np.array_equal(rows, r_rows)
+            assert np.array_equal(split, r_split)
+            assert np.array_equal(byph, r_byph)
+
+    def test_power_of_two_grid_keeps_its_shapes(self):
+        st = _GridState(_sweep(64, (4, 2)))
+        assert _bucket(64) == 64
+        with x64():
+            tab = fused._chain_tables(st, False, 64)
+        assert tab["n_s"].shape == (64,)
+        assert np.array_equal(np.asarray(tab["cand_inst"]), st.cand_inst)
+        assert np.array_equal(np.asarray(tab["cand_mask"]), st.cand_mask)
+
+    def test_one_program_per_bucket(self, monkeypatch):
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(fused, "_SCAN_CACHE", {})
+        for n_cells in (70, 100):
+            swot_greedy_grid(_sweep(n_cells), planner="fused")
+        assert fused._SCAN_CACHE[("chain", 24, False)]._cache_size() == 1
+
+    def test_off_the_chip_each_grid_keeps_its_shape(self, monkeypatch):
+        assert fused.chain_cells_padded(3) == 3
+        monkeypatch.setattr(fused, "_SCAN_CACHE", {})
+        for n_cells in (3, 5):
+            swot_greedy_grid(_sweep(n_cells), planner="fused")
+        assert fused._SCAN_CACHE[("chain", 24, False)]._cache_size() == 2
+
+
+# ---------------------------------------------------------------------------
 # Attribution composes with the fused planner
 # ---------------------------------------------------------------------------
 class TestFusedAttribution:
@@ -190,6 +284,132 @@ class TestSelectPlanner:
         monkeypatch.setenv(ENV_FUSED_PLANNER_THRESHOLD, "soon")
         with pytest.raises(ValueError):
             select_planner_by_size(4)
+
+    def test_chain_grid_off_the_tpu_keeps_the_default(self, monkeypatch):
+        monkeypatch.delenv(ENV_FUSED_PLANNER_THRESHOLD, raising=False)
+        at = DEFAULT_FUSED_PLANNER_THRESHOLD
+        for n_cells, want in ((at - 1, "step"), (at, "fused")):
+            taken = _planner_taken(
+                monkeypatch, "_chain_grid_decisions",
+                lambda: swot_greedy_grid(_sweep(n_cells)),
+            )
+            assert taken == want
+
+    def test_small_chain_plan_leaves_jax_unimported(self):
+        code = (
+            "import sys\n"
+            "from repro.core import OpticalFabric\n"
+            "from repro.core.greedy import swot_greedy_grid\n"
+            "from repro.core.patterns import pairwise_alltoall\n"
+            "cells = [(OpticalFabric(8, 4, t_recfg=t), "
+            "pairwise_alltoall(8, 1e6)) for t in (0.0, 5e-5, 2e-4)]\n"
+            "assert len(swot_greedy_grid(cells)) == 3\n"
+            "assert 'jax' not in sys.modules, 'jax imported'\n"
+        )
+        src = os.path.join(os.path.dirname(__file__), os.pardir, "src")
+        env = {**os.environ, "PYTHONPATH": os.path.abspath(src)}
+        env.pop(ENV_FUSED_PLANNER_THRESHOLD, None)
+        done = subprocess.run(
+            [sys.executable, "-c", code], env=env,
+            capture_output=True, text=True, timeout=120,
+        )
+        assert done.returncode == 0, done.stderr
+
+
+class _Chosen(Exception):
+    """Raised by a spy once the planner a grid took is known."""
+
+
+def _planner_taken(monkeypatch, target: str, call) -> str:
+    """The ``planner`` argument ``call()`` hands ``greedy.<target>``."""
+
+    def spy(st, *args):
+        raise _Chosen(args[-1])
+
+    monkeypatch.setattr(greedy, target, spy)
+    with pytest.raises(_Chosen) as got:
+        call()
+    return got.value.args[0]
+
+
+class TestSelectPlannerOnTpu:
+    """The chip's rule for CHAIN sweep grids, and where it does not reach."""
+
+    @pytest.fixture(autouse=True)
+    def _tpu(self, monkeypatch):
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.delenv(ENV_FUSED_PLANNER_THRESHOLD, raising=False)
+
+    def _chain(self, monkeypatch, n_cells, **kw):
+        return _planner_taken(
+            monkeypatch, "_chain_grid_decisions",
+            lambda: swot_greedy_grid(_sweep(n_cells), **kw),
+        )
+
+    def test_chain_grid_switches_at_the_chip_threshold(self, monkeypatch):
+        at = TPU_CHAIN_FUSED_PLANNER_THRESHOLD
+        assert 1 <= at < DEFAULT_FUSED_PLANNER_THRESHOLD
+        assert self._chain(monkeypatch, at) == "fused"
+        assert self._chain(monkeypatch, 3) == "fused"
+        assert self._chain(monkeypatch, DEFAULT_FUSED_PLANNER_THRESHOLD - 1) \
+            == "fused"
+        # One cell below it (no grid at all where it is 1) keeps the loop.
+        assert select_planner_by_size(at - 1, default_threshold=at) == "step"
+
+    def test_only_the_chip_rule_buckets(self, monkeypatch):
+        at = DEFAULT_FUSED_PLANNER_THRESHOLD
+        assert fused.chain_cells_padded(1) == 1
+        assert fused.chain_cells_padded(3) == 4
+        assert fused.chain_cells_padded(70) == 128
+        assert fused.chain_cells_padded(at - 1) == at
+        assert fused.chain_cells_padded(at + 44) == at + 44
+        b_pad = {}
+
+        def spy(st, horizon, pad=None):
+            b_pad[len(st.cells)] = pad
+            raise _Chosen(pad)
+
+        monkeypatch.setattr(fused, "fused_chain_grid_chosen", spy)
+        for n_cells in (70, at - 1, at + 44):
+            with pytest.raises(_Chosen):
+                swot_greedy_grid(_sweep(n_cells))
+        with pytest.raises(_Chosen):
+            greedy.swot_greedy_chain_batch(_sweep(at + 44))
+        assert b_pad == {70: 128, at - 1: at, at + 44: at + 44}
+
+    def test_independent_grid_keeps_the_default(self, monkeypatch):
+        at = DEFAULT_FUSED_PLANNER_THRESHOLD
+        for n_cells, want in ((at - 1, "step"), (at, "fused")):
+            taken = _planner_taken(
+                monkeypatch, "_independent_grid_decisions",
+                lambda: swot_greedy_grid(
+                    _sweep(n_cells), mode=DependencyMode.INDEPENDENT
+                ),
+            )
+            assert taken == want
+
+    def test_chain_batch_keeps_the_default(self, monkeypatch):
+        at = DEFAULT_FUSED_PLANNER_THRESHOLD
+        for n_cells, want in ((at - 1, "step"), (at, "fused")):
+            taken = _planner_taken(
+                monkeypatch, "_chain_grid_decisions",
+                lambda: greedy.swot_greedy_chain_batch(_sweep(n_cells)),
+            )
+            assert taken == want
+
+    def test_env_and_explicit_still_win(self, monkeypatch):
+        at = TPU_CHAIN_FUSED_PLANNER_THRESHOLD
+        assert self._chain(monkeypatch, at, planner="step") == "step"
+        assert self._chain(monkeypatch, 70, planner="step") == "step"
+        monkeypatch.setenv(ENV_FUSED_PLANNER_THRESHOLD, "100")
+        assert self._chain(monkeypatch, 70) == "step"
+        assert self._chain(monkeypatch, 100) == "fused"
+        monkeypatch.setenv(ENV_FUSED_PLANNER_THRESHOLD, "100000")
+        assert self._chain(monkeypatch, at) == "step"
+        assert self._chain(monkeypatch, at, planner="fused") == "fused"
+        monkeypatch.setenv(ENV_FUSED_PLANNER_THRESHOLD, "1")
+        assert self._chain(monkeypatch, 1) == "fused"
+        assert self._chain(monkeypatch, at, planner="step") == "step"
 
 
 # ---------------------------------------------------------------------------

@@ -2,8 +2,9 @@
 
 Compiles -- without a chip -- the three programs `chip_smoke.py` runs,
 at its phase-(a) shapes (1,024 cells of 128-node pairwise all-to-all,
-127 steps, 8 planes, ``max_enumerated_planes=4``), for one chip of a
-described ``v5e:2x2`` topology.  This is the only file that describes
+127 steps, 8 planes, ``max_enumerated_planes=4``), and the CHAIN scan
+at a padded 128-cell bucket of 4 planes (the small sweep's shape), for
+one chip of a described ``v5e:2x2`` topology.  This is the only file that describes
 the chip: the topology is built inside a fixture, never at import, so
 every test worker collects the same tests and only the worker running
 this file loads the TPU compiler.
@@ -123,6 +124,29 @@ def test_fused_chain_scan_compiles(one_chip):
     with x64():
         tab = fused._chain_tables(st, with_bypass=False)
         assert tab["step_vol"].dtype == np.float64
+        compiled = (
+            fused._chain_scan(_HORIZON, False)
+            .lower(_shapes(tab, one_chip))
+            .compile()
+        )
+    assert compiled.memory_analysis() is not None
+
+
+def test_bucketed_chain_scan_compiles(one_chip):
+    # 100 cells of the a2a128_4p sweeps' shape (4 planes, every reserve
+    # subset enumerated), padded to the 128-cell bucket.
+    pattern = pairwise_alltoall(_NODES, 1e6)
+    cells = [
+        (OpticalFabric(_NODES, 4, t_recfg=12.5e-6 * (1 + i)), pattern)
+        for i in range(100)
+    ]
+    st = _greedy._GridState(
+        cells, mode=DependencyMode.CHAIN, max_enumerated_planes=4
+    )
+    with x64():
+        tab = fused._chain_tables(st, False, 128)
+        assert tab["n_s"].shape == (128,)
+        assert tab["cand_inst"].shape == (128 * 15,)
         compiled = (
             fused._chain_scan(_HORIZON, False)
             .lower(_shapes(tab, one_chip))

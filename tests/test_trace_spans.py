@@ -2,7 +2,9 @@
 
 * ``plan()`` on a grid writes the seven ``repro.*`` spans into the JAX
   profiler's trace, each with its call's ``request`` number and the grid's
-  ``cells``: one number within a call, another for the next call;
+  ``cells``: one number within a call, another for the next call; the
+  fused CHAIN call also names the cell count its scan ran at
+  (``padded``: the power-of-two bucket where the chip rule applies);
 * ``host_span`` is a shared null context while nothing has imported jax,
   and importing ``repro.obs.trace`` imports neither jax nor `repro.core`;
 * the planner's XLA programs keep the names the benchmark reads: the
@@ -91,6 +93,34 @@ def test_plan_spans_carry_one_request_number_per_call(tmp_path):
     # Flat: no program span encloses another.
     for a, b in zip(spans, spans[1:]):
         assert a[2] <= b[1], (a, b)
+
+
+def _fused_call_args(tmp_path, n_cells: int) -> dict:
+    options = PlannerOptions(planner="fused", max_enumerated_planes=4)
+    request = PlanRequest.grid(_cells()[:n_cells], options=options)
+    profile = jax.profiler.ProfileOptions()
+    profile.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=profile):
+        plan(request)
+    (call,) = [s for s in _program_spans(tmp_path)
+               if s[0] == "repro.fused.call"]
+    return call[3]
+
+
+def test_fused_call_span_names_the_padded_bucket(tmp_path, monkeypatch):
+    # Where the chip rule applies (a small CHAIN grid on a TPU).
+    from repro.core.ir import fused
+
+    monkeypatch.setattr(fused, "tpu_chain_rule", lambda n_cells: True)
+    args = _fused_call_args(tmp_path, 3)
+    assert args["cells"] == 3
+    assert args["padded"] == 4
+
+
+def test_fused_call_span_is_unpadded_off_the_chip(tmp_path):
+    args = _fused_call_args(tmp_path, 3)
+    assert args["cells"] == 3
+    assert args["padded"] == 3
 
 
 def test_host_span_is_a_null_context_without_jax(monkeypatch):
