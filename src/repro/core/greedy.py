@@ -74,10 +74,12 @@ from repro.core.ir.backends import (
     DEFAULT_GRID_BACKEND_THRESHOLD,
     ENV_GRID_BACKEND_THRESHOLD,
     TPU_CHAIN_FUSED_PLANNER_THRESHOLD,
+    get_backend,
     select_backend_by_size,
     select_planner_by_size,
     tpu_chain_rule,
 )
+from repro.core.ir.engine import pack_instances
 from repro.core.patterns import Pattern
 from repro.core.schedule import (
     BypassRoute,
@@ -1031,8 +1033,20 @@ def _chain_grid_chosen(
     return chosen
 
 
+# Host span names of one CHAIN grid pass: the fused call, the per-step
+# loop, and the materialization.  The Topology-Bypassing twin pass names
+# its own (``repro.relay.*``), so a trace tells the two passes apart.
+_BASE_SPANS = ("repro.fused.call", "repro.grid.step_loop",
+               "repro.grid.materialize")
+_RELAY_SPANS = ("repro.relay.call", "repro.relay.call",
+                "repro.relay.materialize")
+
+
 def _chain_grid_decisions(
-    st: _GridState, rollout_horizon: int, planner: str = "step"
+    st: _GridState,
+    rollout_horizon: int,
+    planner: str = "step",
+    spans: tuple[str, str, str] = _BASE_SPANS,
 ) -> list[Decisions]:
     """Materialize CHAIN-mode grid Decisions from either planner.
 
@@ -1042,10 +1056,12 @@ def _chain_grid_decisions(
     chosen splits by contract (property-tested), so the materialization
     below is shared verbatim.  The fused scan runs at
     `fused.chain_cells_padded` cells (a power-of-two bucket under the
-    chip rule).
+    chip rule).  ``spans`` names the host spans of the fused call, the
+    step loop and the materialization (`_BASE_SPANS`, `_RELAY_SPANS`).
     """
     b = len(st.cells)
     with_bypass = st.bypass_depth >= 2
+    fused_span, step_span, materialize_span = spans
     if planner == "fused":
         from repro.core.ir.fused import (
             chain_cells_padded,
@@ -1053,13 +1069,13 @@ def _chain_grid_decisions(
         )
 
         b_pad = chain_cells_padded(b)
-        with host_span("repro.fused.call", cells=b, padded=b_pad):
+        with host_span(fused_span, cells=b, padded=b_pad):
             chosen = fused_chain_grid_chosen(st, rollout_horizon, b_pad)
     else:
-        with host_span("repro.grid.step_loop", cells=b):
+        with host_span(step_span, cells=b):
             chosen = _chain_grid_chosen(st, rollout_horizon)
 
-    with host_span("repro.grid.materialize", cells=b):
+    with host_span(materialize_span, cells=b):
         splits: list[list[dict[int, float]]] = [[] for _ in range(b)]
         bypass_steps: list[list[tuple[BypassRoute, ...]]] = [
             [] for _ in range(b)
@@ -1291,47 +1307,11 @@ def swot_greedy_grid(
                         max_enumerated_planes=max_enumerated_planes)
     if mode is DependencyMode.CHAIN:
         decisions = _chain_grid_decisions(st, rollout_horizon, planner)
-        st_byp = None
         if bypass_depth >= 2:
-            with host_span("repro.grid.state", cells=len(cells)):
-                st_byp = _GridState(
-                    cells, mode=mode,
-                    max_enumerated_planes=max_enumerated_planes,
-                    bypass_depth=bypass_depth,
-                )
-        # Mirror the per-instance `depth_tab.any()` guard: a grid with
-        # no self-relay opportunity anywhere (e.g. all xor pairings)
-        # skips the twin pass and its two scoring passes entirely.
-        if st_byp is not None and st_byp.depth_tab.any():
-            byp_decisions = _chain_grid_decisions(
-                st_byp, rollout_horizon, planner
+            decisions = _relay_twin_pass(
+                cells, decisions, rollout_horizon, max_enumerated_planes,
+                bypass_depth, planner,
             )
-            base_cct = batch_evaluate(
-                [
-                    BatchInstance(fabric, pattern, dec)
-                    for (fabric, pattern), dec in zip(cells, decisions)
-                ],
-                backend="numpy",
-            ).cct
-            byp_cct = batch_evaluate(
-                [
-                    BatchInstance(fabric, pattern, dec)
-                    for (fabric, pattern), dec in zip(cells, byp_decisions)
-                ],
-                backend="numpy",
-            ).cct
-            decisions = [
-                byp
-                if (
-                    byp.bypass is not None
-                    and any(byp.bypass)
-                    and byp_cct[bi] < base_cct[bi]
-                )
-                else base
-                for bi, (base, byp) in enumerate(
-                    zip(decisions, byp_decisions)
-                )
-            ]
     elif independent_split:
         decisions = _independent_split_grid_decisions(st, planner)
     else:
@@ -1362,6 +1342,95 @@ def swot_greedy_grid(
             zip(st.cells, decisions)
         )
     ]
+
+
+@dataclasses.dataclass
+class RelayTally:
+    """Running totals of the grid planner's Topology-Bypassing twin pass.
+
+    One process-wide tally (`RELAY_TALLY`), like a metrics counter: it
+    grows in every ``swot_greedy_grid`` call that plans a relay twin, and
+    a reader takes its difference over a window.  ``twin_cells`` counts
+    the cells planned with a twin, ``relay_cells`` those whose pick kept
+    the twin's relays, ``relay_routes`` the relay routes those kept plans
+    carry over all their steps.
+    """
+
+    twin_cells: int = 0
+    relay_cells: int = 0
+    relay_routes: int = 0
+
+    def snapshot(self) -> dict[str, int]:
+        return dataclasses.asdict(self)
+
+
+RELAY_TALLY = RelayTally()
+
+
+def _numpy_ccts(
+    cells: Sequence[tuple[OpticalFabric, Pattern]],
+    decisions: Sequence[Decisions],
+) -> np.ndarray:
+    """CCT of each cell's decisions on the numpy timing backend.
+
+    `batch_evaluate` without its ``repro.ir.*`` spans: the relay pick
+    calls this inside its own span, and program spans never nest."""
+    packed = pack_instances(
+        [BatchInstance(f, p, d) for (f, p), d in zip(cells, decisions)],
+        None,
+    )
+    return get_backend("numpy").derive_timing(packed).cct
+
+
+def _relay_twin_pass(
+    cells: Sequence[tuple[OpticalFabric, Pattern]],
+    decisions: list[Decisions],
+    rollout_horizon: int,
+    max_enumerated_planes: int,
+    bypass_depth: int,
+    planner: str,
+) -> list[Decisions]:
+    """Plan the grid's Topology-Bypassing twin and keep, per cell, its
+    plan where it has relays and strictly beats the base plan's CCT.
+
+    Both CCTs are scored on the deterministic numpy backend, the guarded
+    pick of ``swot_greedy_chain``.  A grid with no self-relay anywhere
+    (``depth_tab`` all zero, e.g. all xor pairings) skips the twin and
+    its scoring, as the per-instance ``depth_tab.any()`` guard does.
+    Host spans: ``repro.relay.state`` (the twin's tables, relay depths
+    included), ``repro.relay.call`` and ``repro.relay.materialize`` (the
+    twin's planning pass), ``repro.relay.pick`` (both scoring passes and
+    the pick); `RELAY_TALLY` counts what was kept.
+    """
+    with host_span("repro.relay.state", cells=len(cells)):
+        st = _GridState(
+            cells, mode=DependencyMode.CHAIN,
+            max_enumerated_planes=max_enumerated_planes,
+            bypass_depth=bypass_depth,
+        )
+    if not st.depth_tab.any():
+        return decisions
+    twin = _chain_grid_decisions(
+        st, rollout_horizon, planner, spans=_RELAY_SPANS
+    )
+    with host_span("repro.relay.pick", cells=len(cells)):
+        base_cct = _numpy_ccts(cells, decisions)
+        twin_cct = _numpy_ccts(cells, twin)
+        kept = [
+            twin[bi].bypass is not None
+            and any(twin[bi].bypass)
+            and twin_cct[bi] < base_cct[bi]
+            for bi in range(len(cells))
+        ]
+        RELAY_TALLY.twin_cells += len(cells)
+        RELAY_TALLY.relay_cells += int(sum(kept))
+        RELAY_TALLY.relay_routes += sum(
+            len(routes)
+            for t, k in zip(twin, kept)
+            if k
+            for routes in t.bypass
+        )
+        return [t if k else d for d, t, k in zip(decisions, twin, kept)]
 
 
 def _slice_attribution(att, bi: int):

@@ -76,17 +76,42 @@ def _bucket(n: int) -> int:
     return 1 << max(0, int(n - 1).bit_length())
 
 
+def relay_buckets(n_routes: int, n_hops: int, p_pad: int) -> tuple[int, int]:
+    """The relay route and hop counts a padded batch is timed at.
+
+    A batch without relays keeps none (``R = H = 0``: the route loops
+    vanish).  Otherwise routes pad to the plane bucket, or the next power
+    of two above it (a self-relay takes one plane, so a step's routes
+    rarely outnumber its planes), and hops to a power of two: the counts
+    follow each grid's decisions, and one program then times every grid
+    of a sweep whose plans relay.
+    """
+    if n_routes == 0:
+        return 0, 0
+    return max(_bucket(n_routes), p_pad), _bucket(n_hops)
+
+
 def pad_packed(
-    packed: dict[str, np.ndarray], b_pad: int, s_pad: int, p_pad: int
+    packed: dict[str, np.ndarray],
+    b_pad: int,
+    s_pad: int,
+    p_pad: int,
+    r_pad: int | None = None,
+    h_pad: int | None = None,
 ) -> dict[str, np.ndarray]:
-    """Pad a packed batch to ``(b_pad, s_pad, p_pad)`` bucket shapes.
+    """Pad a packed batch to ``(b_pad, s_pad, p_pad)`` bucket shapes, and
+    its relay routes and hops to ``r_pad`` and ``h_pad`` where given.
 
     Padded batch rows / steps / planes carry zero volume and False masks,
     so the recurrence leaves them inert; padded bandwidth is 1.0 (never
-    used, but keeps ``volume / bw`` NaN-free).
+    used, but keeps ``volume / bw`` NaN-free); a padded route carries no
+    volume over no plane.
     """
     b, s, p = packed["vol"].shape
-    if (b, s, p) == (b_pad, s_pad, p_pad):
+    _, _, r, h = packed["byp_plane"].shape
+    r_pad = r if r_pad is None else r_pad
+    h_pad = h if h_pad is None else h_pad
+    if (b, s, p, r, h) == (b_pad, s_pad, p_pad, r_pad, h_pad):
         return packed
     from repro.core.ir.engine import NO_CONFIG
 
@@ -105,7 +130,6 @@ def pad_packed(
         "byp_vol": 0.0,
         "byp_plane": -1,
     }
-    r_h = packed["byp_vol"].shape[2:] + packed["byp_plane"].shape[3:]
     tgt_shape = {
         "vol": (b_pad, s_pad, p_pad),
         "step_vol": (b_pad, s_pad),
@@ -117,10 +141,11 @@ def pad_packed(
         "t_recfg": (b_pad,),
         "chain": (b_pad,),
         "ready": (b_pad, p_pad),
-        # Route/hop counts are decision-determined (like the step count):
-        # only batch/steps pad, so bypass-free sweeps keep R = H = 0.
-        "byp_vol": (b_pad, s_pad) + r_h[:1],
-        "byp_plane": (b_pad, s_pad) + r_h,
+        # Route/hop counts are decision-determined: they pad where the
+        # caller asks (`relay_buckets`), and bypass-free sweeps keep
+        # R = H = 0.
+        "byp_vol": (b_pad, s_pad, r_pad),
+        "byp_plane": (b_pad, s_pad, r_pad, h_pad),
     }
     for key, arr in packed.items():
         padded = np.full(tgt_shape[key], fill[key], dtype=arr.dtype)
@@ -453,11 +478,15 @@ class JaxBackend(TimingBackend):
 
     def _padded(self, packed: dict[str, np.ndarray]):
         # Bucket the dimensions that vary continuously with sweep size
-        # (batch, planes); the step count is pattern-determined, so its
-        # distinct values are few and padding it would only buy a copy of
-        # the (B, S, P) volume tensor per call.
+        # (batch, planes) and with the plans (relay routes and hops); the
+        # step count is pattern-determined, so its distinct values are
+        # few and padding it would only buy a copy of the (B, S, P)
+        # volume tensor per call.
         b, s, p = packed["vol"].shape
-        return pad_packed(packed, _bucket(b), s, _bucket(p)), (b, p)
+        _, _, r, h = packed["byp_plane"].shape
+        p_pad = _bucket(p)
+        r_pad, h_pad = relay_buckets(r, h, p_pad)
+        return pad_packed(packed, _bucket(b), s, p_pad, r_pad, h_pad), (b, p)
 
     def derive_timing(
         self, packed: dict[str, np.ndarray], attribution: bool = False

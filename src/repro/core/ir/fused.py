@@ -495,9 +495,6 @@ def _build_chain_scan(horizon: int, with_bypass: bool):
 
     body = functools.partial(_chain_step, horizon, with_bypass)
 
-    # Compiled as ``jit_run``: the program name the benchmark's
-    # ``fused_scan_ms`` reads (pinned by tests/test_trace_spans.py).
-    @jax.jit
     def run(tab):
         s_max = tab["step_cfg"].shape[1]
         carry = (
@@ -512,7 +509,13 @@ def _build_chain_scan(horizon: int, with_bypass: bool):
         _, ys = jax.lax.scan(functools.partial(body, tab), carry, xs)
         return ys
 
-    return run
+    # The XLA program takes its name from the function: the base scan
+    # compiles as ``jit_run`` (the benchmark's ``fused_scan_ms``), its
+    # Topology-Bypassing twin with relay rows as ``jit_relay_run``
+    # (``relay_scan_ms``); pinned by tests/test_trace_spans.py.
+    if with_bypass:
+        run.__name__ = run.__qualname__ = "relay_run"
+    return jax.jit(run)
 
 
 def _build_independent_scan(split_mode: bool):
@@ -558,8 +561,9 @@ def _build_independent_scan(split_mode: bool):
         return ys
 
     # The XLA program takes its name from the function: each scan gets a
-    # name of its own, apart from the CHAIN scan's ``jit_run`` that the
-    # benchmark's ``fused_scan_ms`` reads.
+    # name of its own, apart from the CHAIN scans' ``jit_run`` and
+    # ``jit_relay_run`` that the benchmark's ``fused_scan_ms`` and
+    # ``relay_scan_ms`` read.
     run.__name__ = run.__qualname__ = (
         "independent_split_scan" if split_mode else "independent_pack_scan"
     )

@@ -42,6 +42,7 @@ from repro.core.ir.backends import (
     _bucket,
     get_backend,
     pad_packed,
+    relay_buckets,
     resolve_backend,
 )
 from repro.core.ir.engine import pack_instances
@@ -251,6 +252,44 @@ class TestBucketing:
         for n in (3, 4):  # both bucket to batch=4
             padded, _ = jb._padded(pack_instances([inst] * n, None))
             assert padded["vol"].shape[0] == 4
+
+    def test_jax_buckets_relay_routes_and_hops(self):
+        """Grids whose plans relay differently share one timing shape
+        (routes padded to the plane bucket, hops to a power of two), a
+        relay-free batch keeps none, and the padded routes are inert."""
+        try:
+            jb = JaxBackend()
+        except BackendUnavailable as exc:
+            pytest.skip(str(exc))
+        assert relay_buckets(0, 0, 4) == (0, 0)
+        assert relay_buckets(1, 2, 4) == relay_buckets(3, 2, 4) == (4, 2)
+        assert relay_buckets(5, 3, 4) == (8, 4)
+        cells = [
+            (
+                OpticalFabric(16, 4, t_recfg=t),
+                get_pattern("bruck_alltoall", 16, size),
+            )
+            for size in (1e6, 3e7)
+            for t in (1e-3, 3e-3)
+        ]
+        plans = swot_greedy_grid(cells, backend="numpy", bypass_depth=2)
+        instances = [
+            BatchInstance(g.fabric, g.pattern, g.decisions) for g in plans
+        ]
+        assert any(g.decisions.bypass for g in plans)
+        shapes = set()
+        for batch in (instances, instances[:1], instances[1:]):
+            packed = pack_instances(batch, None)
+            if packed["byp_plane"].shape[2]:
+                padded, _ = jb._padded(packed)
+                shapes.add(padded["byp_plane"].shape[2:])
+            np.testing.assert_array_equal(
+                jb.derive_timing(packed).cct,
+                get_backend("numpy").derive_timing(packed).cct,
+            )
+        assert shapes == {(4, 2)}
+        free = pack_instances([strawman_instance(*cells[0])], None)
+        assert jb._padded(free)[0]["byp_plane"].shape[2:] == (0, 0)
 
 
 class TestBackendSelection:

@@ -2,9 +2,12 @@
 
 Compiles -- without a chip -- the three programs `chip_smoke.py` runs,
 at its phase-(a) shapes (1,024 cells of 128-node pairwise all-to-all,
-127 steps, 8 planes, ``max_enumerated_planes=4``), and the CHAIN scan
-at a padded 128-cell bucket of 4 planes (the small sweep's shape), for
-one chip of a described ``v5e:2x2`` topology.  This is the only file that describes
+127 steps, 8 planes, ``max_enumerated_planes=4``), the CHAIN scan
+at a padded 128-cell bucket of 4 planes (the small sweep's shape), and
+the Topology-Bypassing programs of a relay sweep (1,024 cells of
+512-rank Bruck all-to-all, 9 steps, 4 planes, ``bypass_depth=2``: the
+twin scan and the timing scan with relay rows), for one chip of a
+described ``v5e:2x2`` topology.  This is the only file that describes
 the chip: the topology is built inside a fixture, never at import, so
 every test worker collects the same tests and only the worker running
 this file loads the TPU compiler.
@@ -23,7 +26,11 @@ jax = pytest.importorskip("jax")
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
 import repro  # noqa: E402
-from repro.core import OpticalFabric, pairwise_alltoall  # noqa: E402
+from repro.core import (  # noqa: E402
+    OpticalFabric,
+    bruck_alltoall,
+    pairwise_alltoall,
+)
 from repro.core import greedy as _greedy  # noqa: E402
 from repro.core.baselines import strawman_instance  # noqa: E402
 from repro.core.ir import fused, x64  # noqa: E402
@@ -32,6 +39,7 @@ from repro.core.ir.backends import (  # noqa: E402
     _build_jax_timing,
     _bucket,
     pad_packed,
+    relay_buckets,
 )
 from repro.core.ir.engine import pack_instances  # noqa: E402
 from repro.core.schedule import DependencyMode  # noqa: E402
@@ -90,6 +98,16 @@ def _shapes(tree, sharding):
     )
 
 
+def _timing_args(padded):
+    return [
+        padded[k]
+        for k in (
+            "vol", "step_vol", "step_cfg", "step_mask", "plane_mask", "bw",
+            "init", "t_recfg", "chain", "ready", "byp_vol", "byp_plane",
+        )
+    ]
+
+
 @pytest.mark.parametrize("attribution", [False, True])
 def test_jax_timing_scan_compiles(one_chip, attribution):
     inst = strawman_instance(
@@ -99,13 +117,7 @@ def test_jax_timing_scan_compiles(one_chip, attribution):
     _, s, p = packed["vol"].shape
     padded = pad_packed(packed, _bucket(_SIDE * _SIDE), s, _bucket(p))
     assert padded["vol"].shape == (1024, 127, 8)
-    args = [
-        padded[k]
-        for k in (
-            "vol", "step_vol", "step_cfg", "step_mask", "plane_mask", "bw",
-            "init", "t_recfg", "chain", "ready", "byp_vol", "byp_plane",
-        )
-    ]
+    args = _timing_args(padded)
     with x64():
         compiled = (
             _build_jax_timing(attribution)
@@ -149,6 +161,58 @@ def test_bucketed_chain_scan_compiles(one_chip):
         assert tab["cand_inst"].shape == (128 * 15,)
         compiled = (
             fused._chain_scan(_HORIZON, False)
+            .lower(_shapes(tab, one_chip))
+            .compile()
+        )
+    assert compiled.memory_analysis() is not None
+
+
+_RELAY_NODES, _RELAY_PLANES = 512, 4
+
+
+@pytest.mark.parametrize("attribution", [False, True])
+def test_jax_timing_scan_with_relay_rows_compiles(one_chip, attribution):
+    inst = strawman_instance(
+        OpticalFabric(_RELAY_NODES, _RELAY_PLANES),
+        bruck_alltoall(_RELAY_NODES, 1e6),
+    )
+    packed = pack_instances([inst], None)
+    _, s, p = packed["vol"].shape
+    r_pad, h_pad = relay_buckets(3, 2, _bucket(p))
+    padded = pad_packed(
+        packed, _bucket(_SIDE * _SIDE), s, _bucket(p), r_pad, h_pad
+    )
+    assert padded["byp_plane"].shape == (1024, 9, 4, 2)
+    with x64():
+        compiled = (
+            _build_jax_timing(attribution)
+            .lower(*_shapes(_timing_args(padded), one_chip))
+            .compile()
+        )
+    assert compiled.memory_analysis() is not None
+
+
+def test_relay_twin_scan_compiles(one_chip):
+    pattern = bruck_alltoall(_RELAY_NODES, 1e6)
+    cells = [
+        (
+            OpticalFabric(
+                _RELAY_NODES, _RELAY_PLANES, t_recfg=8e-4 * (1 + i / 512)
+            ),
+            pattern,
+        )
+        for i in range(_SIDE * _SIDE)
+    ]
+    st = _greedy._GridState(
+        cells, mode=DependencyMode.CHAIN,
+        max_enumerated_planes=_ENUM_PLANES, bypass_depth=2,
+    )
+    assert st.depth_tab.any()
+    with x64():
+        tab = fused._chain_tables(st, with_bypass=True)
+        assert tab["cand_inst"].shape == (1024 * 15,)
+        compiled = (
+            fused._chain_scan(_HORIZON, True)
             .lower(_shapes(tab, one_chip))
             .compile()
         )

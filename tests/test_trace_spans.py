@@ -7,10 +7,13 @@
   (``padded``: the power-of-two bucket where the chip rule applies);
 * ``host_span`` is a shared null context while nothing has imported jax,
   and importing ``repro.obs.trace`` imports neither jax nor `repro.core`;
+* a grid planned with Topology Bypassing adds the twin pass's four flat
+  ``repro.relay.*`` spans (state, call, materialize, pick) and grows the
+  planner's relay tally (``greedy.RELAY_TALLY``);
 * the planner's XLA programs keep the names the benchmark reads: the
-  CHAIN scan ``jit_run`` (``fused_scan_ms``) and the timing scan
-  ``jit_fn`` (``timing_scan_ms``), and no other planner program takes
-  either name.
+  CHAIN scan ``jit_run`` (``fused_scan_ms``), its relay twin
+  ``jit_relay_run`` (``relay_scan_ms``) and the timing scan ``jit_fn``
+  (``timing_scan_ms``), and no other planner program takes any of them.
 """
 
 import pathlib
@@ -93,6 +96,55 @@ def test_plan_spans_carry_one_request_number_per_call(tmp_path):
     # Flat: no program span encloses another.
     for a, b in zip(spans, spans[1:]):
         assert a[2] <= b[1], (a, b)
+
+
+RELAY_SPANS = {
+    "repro.relay.state",
+    "repro.relay.call",
+    "repro.relay.materialize",
+    "repro.relay.pick",
+}
+
+
+@pytest.mark.parametrize("planner", ["fused", "step"])
+def test_bypass_grid_writes_the_relay_spans_and_grows_the_tally(
+    tmp_path, planner
+):
+    from repro.core import greedy
+
+    cells = [
+        (
+            OpticalFabric(16, 4, bandwidth=25e9, t_recfg=t_recfg),
+            get_pattern("bruck_alltoall", 16, size),
+        )
+        for size in (1e6, 4e7)
+        for t_recfg in (1e-3, 3e-3)
+    ]
+    options = PlannerOptions(
+        planner=planner, backend="jax", max_enumerated_planes=4,
+        bypass_depth=2,
+    )
+    before = greedy.RELAY_TALLY.snapshot()
+    profile = jax.profiler.ProfileOptions()
+    profile.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=profile):
+        result = plan(PlanRequest.grid(cells, options=options))
+    after = greedy.RELAY_TALLY.snapshot()
+    spans = _program_spans(tmp_path)
+    names = {s[0] for s in spans}
+    assert names >= RELAY_SPANS
+    assert names - RELAY_SPANS == SPANS - {
+        "repro.grid.step_loop" if planner == "fused" else "repro.fused.call"
+    }
+    assert all(s[3]["cells"] == 4 for s in spans)
+    for a, b in zip(spans, spans[1:]):
+        assert a[2] <= b[1], (a, b)
+    kept = [g for g in result.grid if g.plan.decisions.bypass]
+    assert after["twin_cells"] - before["twin_cells"] == 4
+    assert after["relay_cells"] - before["relay_cells"] == len(kept) > 0
+    assert after["relay_routes"] - before["relay_routes"] == sum(
+        len(routes) for g in kept for routes in g.plan.decisions.bypass
+    )
 
 
 def _fused_call_args(tmp_path, n_cells: int) -> dict:
@@ -198,7 +250,9 @@ def test_program_names_the_benchmark_reads():
             names[("timing", att)] = _program_name(
                 _build_jax_timing(att).lower(*timing_args)
             )
-    assert names[("chain", False)] == names[("chain", True)] == "jit_run"
+    assert names[("chain", False)] == "jit_run"
+    assert names[("chain", True)] == "jit_relay_run"
     assert names[("timing", False)] == names[("timing", True)] == "jit_fn"
     others = {v for k, v in names.items() if k[0] == "independent"}
-    assert len(others) == 2 and not others & {"jit_run", "jit_fn"}
+    assert len(others) == 2
+    assert not others & {"jit_run", "jit_relay_run", "jit_fn"}
