@@ -33,6 +33,8 @@ installed permutations ``p0, p1`` realizes ``x -> p1[p0[x]]``.
 
 from __future__ import annotations
 
+from typing import Sequence
+
 import numpy as np
 
 from repro.core.patterns import Pattern
@@ -91,6 +93,34 @@ def relay_depth_table(pattern: Pattern, max_depth: int) -> np.ndarray:
         for c, pc in perms.items():
             table[a, c] = self_relay_depth(pa, pc, max_depth)
     return table
+
+
+def relay_depth_tables(
+    patterns: Sequence[Pattern], max_depth: int
+) -> list[np.ndarray]:
+    """`relay_depth_table` of each pattern, built once per distinct mapping.
+
+    A table depends only on the pattern's config id -> pairing mapping, so
+    patterns that share one (a grid's message sizes of one algorithm and
+    node count) share one table: the same array object is returned for
+    each of them.  Patterns are grouped first by identity, then by the
+    mapping's content, in config-id order.
+    """
+    by_object: dict[int, np.ndarray] = {}
+    by_mapping: dict[tuple, np.ndarray] = {}
+    tables = []
+    for pattern in patterns:
+        table = by_object.get(id(pattern))
+        if table is None:
+            key = tuple(sorted(config_perms(pattern).items()))
+            table = by_mapping.get(key)
+            if table is None:
+                table = by_mapping[key] = relay_depth_table(
+                    pattern, max_depth
+                )
+            by_object[id(pattern)] = table
+        tables.append(table)
+    return tables
 
 
 def enumerate_relay_routes(

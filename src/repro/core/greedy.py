@@ -57,7 +57,7 @@ from typing import TYPE_CHECKING, Sequence
 
 import numpy as np
 
-from repro.core.bypass import relay_depth_table
+from repro.core.bypass import relay_depth_table, relay_depth_tables
 from repro.core.fabric import OpticalFabric
 from repro.core.ir import (
     NO_CONFIG,
@@ -686,12 +686,15 @@ class _GridState:
         self.installed = self.config.copy()
         # Per-instance self-relay depth tables, padded to the grid's max
         # config-id range; all-zero (shape (B, 0, 0)) when bypassing is
-        # off, which turns the bypass row expansion into a no-op.
+        # off, which turns the bypass row expansion into a no-op.  Cells
+        # whose patterns share a config -> pairing mapping share a table,
+        # built once (`n_depth_tables` of them).
+        self.n_depth_tables = 0
         if mode is DependencyMode.CHAIN and bypass_depth >= 2:
-            tabs = [
-                relay_depth_table(pattern, bypass_depth)
-                for _, pattern in cells
-            ]
+            tabs = relay_depth_tables(
+                [pattern for _, pattern in cells], bypass_depth
+            )
+            self.n_depth_tables = len({id(t) for t in tabs})
             c_max = max(t.shape[0] for t in tabs)
             self.depth_tab = np.zeros((b, c_max, c_max), dtype=np.int64)
             for bi, t in enumerate(tabs):
@@ -1353,12 +1356,15 @@ class RelayTally:
     a reader takes its difference over a window.  ``twin_cells`` counts
     the cells planned with a twin, ``relay_cells`` those whose pick kept
     the twin's relays, ``relay_routes`` the relay routes those kept plans
-    carry over all their steps.
+    carry over all their steps, ``depth_tables`` the distinct self-relay
+    depth tables the twins' grid states built (one per config -> pairing
+    mapping in a grid).
     """
 
     twin_cells: int = 0
     relay_cells: int = 0
     relay_routes: int = 0
+    depth_tables: int = 0
 
     def snapshot(self) -> dict[str, int]:
         return dataclasses.asdict(self)
@@ -1400,7 +1406,8 @@ def _relay_twin_pass(
     Host spans: ``repro.relay.state`` (the twin's tables, relay depths
     included), ``repro.relay.call`` and ``repro.relay.materialize`` (the
     twin's planning pass), ``repro.relay.pick`` (both scoring passes and
-    the pick); `RELAY_TALLY` counts what was kept.
+    the pick); `RELAY_TALLY` counts the depth tables built and what was
+    kept.
     """
     with host_span("repro.relay.state", cells=len(cells)):
         st = _GridState(
@@ -1408,6 +1415,7 @@ def _relay_twin_pass(
             max_enumerated_planes=max_enumerated_planes,
             bypass_depth=bypass_depth,
         )
+    RELAY_TALLY.depth_tables += st.n_depth_tables
     if not st.depth_tab.any():
         return decisions
     twin = _chain_grid_decisions(

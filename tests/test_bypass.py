@@ -29,13 +29,16 @@ from repro.core import (
     to_ir,
     validate_ir,
 )
+from repro.core import bypass
 from repro.core.bypass import (
     compose,
     config_perms,
     relay_depth_table,
+    relay_depth_tables,
     self_relay_depth,
 )
 from repro.core.greedy import (
+    _GridState,
     _chain_decisions,
     independent_decisions,
     independent_split_decisions,
@@ -348,6 +351,64 @@ class TestBypassGrid:
             alone = swot_greedy_grid([cell], bypass_depth=2)[0]
             assert together[k].decisions == alone.decisions, k
             assert together[k].cct == alone.cct
+
+
+class TestGridDepthTables:
+    """A grid builds one self-relay depth table per distinct config ->
+    pairing mapping, and pads exactly what per-cell tables would give."""
+
+    def _cells(self):
+        bruck16 = get_pattern("bruck_alltoall", 16, 1e6)
+        patterns = [
+            bruck16,
+            get_pattern("bruck_alltoall", 16, 4e7),
+            get_pattern("bruck_alltoall", 16, 1e6),
+            get_pattern("pairwise_alltoall", 16, 8e6),
+            get_pattern("bruck_alltoall", 32, 8e6),
+            get_pattern("ring_allreduce", 16, 8e6),
+        ]
+        return [
+            (OpticalFabric(p.n_nodes, 4, t_recfg=t_recfg), p)
+            for p in patterns
+            for t_recfg in (2e-4, 3.2e-3)
+        ]
+
+    @pytest.mark.parametrize("depth", [2, 3])
+    def test_padded_tables_match_per_cell_tables(self, depth):
+        cells = self._cells()
+        st = _GridState(cells, bypass_depth=depth)
+        tabs = [relay_depth_table(p, depth) for _, p in cells]
+        c_max = max(t.shape[0] for t in tabs)
+        want = np.zeros((len(cells), c_max, c_max), dtype=np.int64)
+        for bi, t in enumerate(tabs):
+            want[bi, : t.shape[0], : t.shape[1]] = t
+        assert st.depth_tab.dtype == want.dtype
+        np.testing.assert_array_equal(st.depth_tab, want)
+        assert st.n_depth_tables == 4
+
+    def test_one_table_per_distinct_mapping(self, monkeypatch):
+        built = []
+        table = bypass.relay_depth_table
+
+        def counted(pattern, max_depth):
+            built.append(pattern)
+            return table(pattern, max_depth)
+
+        monkeypatch.setattr(bypass, "relay_depth_table", counted)
+        patterns = [p for _, p in self._cells()]
+        tabs = relay_depth_tables(patterns, 2)
+        assert len(built) == 4
+        assert len({id(t) for t in tabs}) == 4
+        # Bruck-16 at two sizes, and built twice, shares one table.
+        assert tabs[0] is tabs[2] is tabs[4]
+        assert tabs[5] is not tabs[6]
+
+    @pytest.mark.parametrize("depth", [0, 1])
+    def test_depth_below_two_builds_no_table(self, depth):
+        cells = self._cells()
+        st = _GridState(cells, bypass_depth=depth)
+        assert st.depth_tab.shape == (len(cells), 0, 0)
+        assert st.n_depth_tables == 0
 
 
 class TestBypassBatchPadding:

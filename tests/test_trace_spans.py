@@ -141,6 +141,8 @@ def test_bypass_grid_writes_the_relay_spans_and_grows_the_tally(
         assert a[2] <= b[1], (a, b)
     kept = [g for g in result.grid if g.plan.decisions.bypass]
     assert after["twin_cells"] - before["twin_cells"] == 4
+    # One Bruck-16 mapping across both sizes: one depth table.
+    assert after["depth_tables"] - before["depth_tables"] == 1
     assert after["relay_cells"] - before["relay_cells"] == len(kept) > 0
     assert after["relay_routes"] - before["relay_routes"] == sum(
         len(routes) for g in kept for routes in g.plan.decisions.bypass
