@@ -54,9 +54,7 @@ What is deliberately NOT gated:
 * absolute wall-clock rows (``*_wall_time``, ``ir_sweep_*``,
   ``indep_grid_*``, ``ir_backend_*``, ``fused_grid_*`` microsecond
   columns, including the ``*_compile`` cold-start rows and the
-  ``*_us`` phase rows) -- runner hardware varies run to run;
-* the ``pallas`` backend ratio -- interpret mode on CPU times the
-  interpreter, not the kernel.
+  ``*_us`` phase rows) -- runner hardware varies run to run.
 
 A point present in the baseline but missing from the current run fails
 too (a silently dropped gate is itself rot); new points are reported
@@ -108,8 +106,6 @@ _RATE_ROW = re.compile(r"(miss_rate|exposed_frac)$")
 # (below 10% of baseline) fails.
 _WIDE_BAND_ROW = re.compile(r"(events_per_sec|_speedup)$")
 _WIDE_BAND = 0.90
-# Backends whose speedup ratio is not meaningful on CI hosts.
-_UNGATED_BACKENDS = frozenset({"pallas"})
 
 # Hard floors the benchmarks themselves assert in-run (ir_sweep's >= 2x
 # gates).  The band floor is clamped to never exceed these: a baseline
@@ -144,7 +140,7 @@ def _sweep_points(payload: dict) -> dict[str, float]:
 def _speedup_ratios(payload: dict) -> dict[str, float]:
     ratios: dict[str, float] = {}
     for name, entry in payload.get("backends", {}).items():
-        if name in _UNGATED_BACKENDS or "speedup_vs_numpy" not in entry:
+        if "speedup_vs_numpy" not in entry:
             continue
         ratios[f"backend_speedup:{name}"] = float(
             entry["speedup_vs_numpy"]

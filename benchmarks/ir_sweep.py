@@ -10,20 +10,15 @@ Two sweeps, two acceptance gates:
   ``validate_object`` oracle) and in ONE `repro.core.ir.batch_evaluate`
   pass.  Per-instance CCTs must agree within 1e-9 and the batched pass
   must be >= 5x faster (gated for the default numpy backend; pass
-  ``--backend jax|pallas`` to time an accelerator backend instead --
-  parity still asserted).
+  ``--backend jax`` to time the accelerator backend instead -- parity
+  still asserted).
 * ``backend_throughput`` -- the LARGE grid (32 sizes x 32 delays of
   128-node pairwise all-to-all, 127 steps): one packed batch evaluated by
   every available timing backend, with cold (first call: trace+compile)
   and warm timings reported separately (``compile_ms`` is an ungated
   wall-clock row; the gate only sees warm numbers).  The jax backend
   must be >= 2x faster than the numpy reference on this grid (CPU jit
-  counts); the Pallas backend runs in interpret mode for functional
-  parity only (its wall time on CPU is the interpreter's, not the
-  kernel's) -- a compiled-mode (``interpret=False``) probe runs once.
-  On the CPU its refusal is recorded in the payload; on an accelerator
-  a failure to compile fails the run.
-  ``run.py`` dumps these numbers to ``BENCH_backends.json`` for the
+  counts).  ``run.py`` dumps these numbers to ``BENCH_backends.json`` for the
   cross-PR perf trajectory.
 
 A fifth gate, ``fused_grid``, times the fused on-device CHAIN planner
@@ -105,7 +100,7 @@ def run(
 ) -> list[tuple[str, float, str]]:
     del quick  # the 64-cell sweep IS the CI smoke test
     # Resolve now so the row tag and the numpy-only gate reflect what is
-    # actually timed (backend=None follows REPRO_IR_BACKEND).
+    # actually timed (backend=None is numpy).
     backend = resolve_backend(backend).name
     instances = _instances()
     n = len(instances)
@@ -288,24 +283,22 @@ def bypass_sweep(quick: bool = False) -> list[tuple[str, float, str]]:
     byp = swot_greedy_grid(
         cells, backend="numpy", bypass_depth=_BYPASS_DEPTH
     )
-    # Every available accelerator backend must reproduce the numpy CCTs
+    # The jax backend, where available, must reproduce the numpy CCTs
     # bitwise on this bypass batch (relay routes + fractional-bandwidth
-    # splits): the pallas kernel handles bypass natively now, so this
-    # in-run check keeps the no-numpy-delegation contract measured, not
-    # assumed.
+    # splits).
     byp_insts = [
         BatchInstance(fabric, pattern, y.decisions)
         for (fabric, pattern), y in zip(cells, byp)
     ]
     ref = batch_evaluate(byp_insts, backend="numpy")
-    for name in ("jax", "pallas"):
-        try:
-            get_backend(name)
-        except BackendUnavailable:
-            continue
-        got = batch_evaluate(byp_insts, backend=name)
+    try:
+        get_backend("jax")
+    except BackendUnavailable:
+        pass
+    else:
+        got = batch_evaluate(byp_insts, backend="jax")
         assert np.array_equal(got.cct, ref.cct), (
-            f"{name} backend CCT diverges from numpy on bypass batch"
+            "jax backend CCT diverges from numpy on bypass batch"
         )
     rows = []
     for (fabric, _), b, y in zip(cells, base, byp):
@@ -418,7 +411,7 @@ def attribution_rows(quick: bool = False) -> list[tuple[str, float, str]]:
         BatchInstance(p.fabric, p.pattern, p.decisions) for p in plans
     ]
     eff = hidden = exposed = None
-    for name in ("numpy", "jax", "pallas"):
+    for name in ("numpy", "jax"):
         try:
             get_backend(name)
         except BackendUnavailable:
@@ -508,7 +501,7 @@ def backend_throughput(quick: bool = False) -> dict:
         "backends": {},
     }
     engines = {}
-    for name in ("numpy", "jax", "pallas"):
+    for name in ("numpy", "jax"):
         try:
             engines[name] = get_backend(name)
         except BackendUnavailable as exc:
@@ -525,10 +518,8 @@ def backend_throughput(quick: bool = False) -> dict:
     # Interleave the timed reps across backends so a load spike on the
     # host (CI runners are shared) skews every backend alike instead of
     # flipping the gated ratio.
-    for rep in range(5):
+    for _ in range(5):
         for name, engine in engines.items():
-            if name == "pallas" and rep >= 2:
-                continue  # interpret mode is slow; 2 reps suffice
             t0 = time.perf_counter()
             results[name] = engine.derive_timing(packed)
             best[name] = min(best[name], time.perf_counter() - t0)
@@ -563,56 +554,12 @@ def backend_throughput(quick: bool = False) -> dict:
             f"jax backend only {jax_entry['speedup_vs_numpy']}x vs numpy "
             "on the large grid (acceptance gate is >= 2x)"
         )
-    # Compiled-pallas probe: interpret=False compiles the actual Mosaic/
-    # Triton kernel, which needs a TPU/GPU backend (see the probe).
-    payload["pallas_compiled"] = _pallas_compiled_probe()
     # The INDEPENDENT-mode grid gate rides along in the same payload so
     # BENCH_backends.json tracks both batching trajectories per PR,
     # as does the fused on-device planner gate.
     payload["independent_grid"] = independent_grid()
     payload["fused_grid"] = fused_grid()
     return payload
-
-
-def _pallas_compiled_probe() -> dict:
-    """Try the pallas kernel with ``interpret=False`` on a small batch.
-
-    On the CPU, Pallas refuses anything but interpret mode; that refusal
-    is recorded as data.  On an accelerator any failure to lower,
-    compile or run propagates and fails the benchmark.
-    """
-    import jax
-
-    from repro.core.ir.backends import PallasBackend
-
-    probe = [
-        strawman_instance(
-            OpticalFabric(8, 4, t_recfg=25e-6),
-            pairwise_alltoall(8, 1e6),
-            prestage=True,
-        )
-    ]
-    try:
-        backend = PallasBackend(interpret=False)
-    except BackendUnavailable as exc:
-        return {"available": False, "error": str(exc)}
-    packed = pack_instances(probe, None)
-    try:
-        backend.derive_timing(packed)  # compile + run
-    except ValueError as exc:
-        if jax.default_backend() != "cpu" or "interpret mode" not in str(exc):
-            raise
-        return {"available": False, "error": f"{type(exc).__name__}: {exc}"}
-    t0 = time.perf_counter()
-    result = backend.derive_timing(packed)
-    warm_ms = (time.perf_counter() - t0) * 1e3
-    ref = get_backend("numpy").derive_timing(pack_instances(probe, None))
-    err = float(np.max(np.abs(result.cct - ref.cct)))
-    return {
-        "available": True,
-        "warm_ms": round(warm_ms, 3),
-        "max_cct_err_vs_numpy": err,
-    }
 
 
 # Fused-planner gate: same 1024-cell grid as ``backend_throughput`` but
@@ -781,10 +728,9 @@ if __name__ == "__main__":
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument(
         "--backend",
-        choices=("numpy", "jax", "pallas"),
+        choices=("numpy", "jax"),
         default=None,
-        help="IR timing backend for the 64-cell sweep "
-        "(default: REPRO_IR_BACKEND env, else numpy)",
+        help="IR timing backend for the 64-cell sweep (default: numpy)",
     )
     cli = parser.parse_args()
     from repro.obs import get_logger

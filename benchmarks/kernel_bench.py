@@ -109,45 +109,6 @@ def run() -> list[tuple[str, float, str]]:
     us = _time(fn, x, w)
     rows.append(("kernel_rmsnorm_pallas_interpret", us, "functional"))
 
-    # Schedule-IR timing scan (the batched sweep recurrence).  Interpret
-    # mode wall time is the interpreter's; the parity vs the numpy
-    # backend is the signal (also gated in tests/test_ir_backends.py).
-    import numpy as np
-
-    from repro.core import OpticalFabric, get_pattern, strawman_instance
-    from repro.core.ir import get_backend
-    from repro.core.ir.engine import pack_instances
-    from repro.kernels.timing_scan import timing_scan
-
-    instances = [
-        strawman_instance(
-            OpticalFabric(8, 4, t_recfg=25e-6 * (1 + k)),
-            get_pattern("rabenseifner_allreduce", 8, 1e6 * (1 + k)),
-            prestage=True,
-        )
-        for k in range(32)
-    ]
-    packed = pack_instances(instances, None)
-    from repro.core.ir import x64
-
-    with x64():
-        fn = lambda: timing_scan(packed, interpret=True)
-        jax.block_until_ready(fn()[0])
-        t0 = time.perf_counter()
-        cct = fn()[0]
-        jax.block_until_ready(cct)
-        us = (time.perf_counter() - t0) * 1e6
-    err = float(
-        np.max(np.abs(np.asarray(cct) - get_backend("numpy")
-                      .derive_timing(packed).cct))
-    )
-    rows.append(
-        (
-            "kernel_timing_scan_pallas_interpret",
-            us,
-            f"{len(instances)} cells max_cct_err={err:.1e}",
-        )
-    )
     return rows
 
 

@@ -196,7 +196,7 @@ def run(
         rate, horizon = 30.0, 0.5
     # Whole-sweep lockstep-ICR reference: every (cell, collective
     # signature) pair becomes one row of a single batched IR evaluation
-    # (timing backend follows REPRO_IR_BACKEND, like every IR sweep).
+    # (on the numpy timing backend, like every IR sweep by default).
     t0 = time.perf_counter()
     ref_keys: list[tuple[int, tuple]] = []
     ref_instances: list[BatchInstance] = []

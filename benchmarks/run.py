@@ -30,7 +30,7 @@ so neither ever clobbers the tracked file.  The committed flavor is the
 change.
 
 Unfiltered runs additionally write the IR timing-backend throughput
-comparison (numpy vs jax vs pallas-interpret on the large ``ir_sweep``
+comparison (numpy vs jax on the large ``ir_sweep``
 grid, cold/compile and warm timed separately, including the >= 2x
 jax-vs-numpy acceptance gate) plus the fused on-device planner gate
 (``fused_grid``: the whole CHAIN greedy loop as one jitted ``lax.scan``,

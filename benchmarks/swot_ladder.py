@@ -55,7 +55,7 @@ def run() -> list[tuple[str, float, str]]:
             cells.append((req, planes, fabric, pattern))
 
     # Every cell's strawman baseline in ONE batched IR pass (the timing
-    # backend follows REPRO_IR_BACKEND: numpy default, jax/pallas opt-in).
+    # backend is numpy unless one is named).
     straw_ccts = batch_evaluate(
         [
             strawman_instance(fabric, pattern)

@@ -13,7 +13,7 @@ Phases:
     CHAIN grid (128-node pairwise all-to-all, 127 steps, 8 planes;
     32 message sizes of 1-32 MB x 32 reconfiguration delays of
     12.5-400 us; ``max_enumerated_planes=4``, rollout horizon 24).  The
-    size-based selection must pick the fused ``lax.scan`` planner and
+    grid's route must pick the fused ``lax.scan`` planner and
     the jax timing backend, with the planner's tables on the TPU.  Every
     device plan must be legal, timed by the device as the numpy executor
     times it, and faster than the strawman.  Its decisions and numpy
@@ -151,28 +151,14 @@ def phase_a(probe: _PathProbe, side: int = _SIDE):
     from repro.core import BatchInstance, batch_evaluate
     from repro.core.api import PlannerOptions, PlanRequest, plan
     from repro.core.baselines import strawman_instance
-    from repro.core.ir import (
-        select_backend_by_size,
-        select_planner_by_size,
-        to_ir,
-        validate_ir,
-    )
-    from repro.core.ir.backends import (
-        DEFAULT_GRID_BACKEND_THRESHOLD,
-        ENV_GRID_BACKEND_THRESHOLD,
-    )
+    from repro.core.ir import route, to_ir, validate_ir
     from repro.core.tolerances import times_close_arr
 
     cells = _grid_cells(side)
     n = len(cells)
-    _check(select_planner_by_size(n) == "fused", "fused planner chosen")
-    _check(
-        select_backend_by_size(
-            n, ENV_GRID_BACKEND_THRESHOLD, DEFAULT_GRID_BACKEND_THRESHOLD
-        )
-        == "jax",
-        "jax backend chosen",
-    )
+    taken = route(n, chain=True)
+    _check(taken.planner == "fused", "fused planner chosen")
+    _check(taken.backend == "jax", "jax backend chosen")
     opts = PlannerOptions(
         max_enumerated_planes=_ENUM_PLANES, rollout_horizon=_HORIZON
     )

@@ -21,7 +21,7 @@ trace-event JSON (one thread row per plane; plans laid out
 back-to-back), loadable at https://ui.perfetto.dev.
 
     PYTHONPATH=src python examples/optical_schedule_demo.py \
-        [--backend numpy|jax|pallas] [--bypass] [--bypass-depth H] \
+        [--backend numpy|jax] [--bypass] [--bypass-depth H] \
         [--trace out.json]
 """
 
@@ -54,10 +54,9 @@ def main() -> None:
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument(
         "--backend",
-        choices=("numpy", "jax", "pallas"),
+        choices=("numpy", "jax"),
         default=None,
-        help="IR timing backend for the what-if sweep "
-        "(default: REPRO_IR_BACKEND env, else numpy)",
+        help="IR timing backend for the what-if sweep (default: numpy)",
     )
     parser.add_argument(
         "--bypass",

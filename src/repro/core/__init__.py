@@ -54,7 +54,6 @@ from repro.core.ir import (
     IRMetrics,
     ScheduleIR,
     TimingBackend,
-    available_backends,
     batch_evaluate,
     evaluate_decisions,
     execute_ir,
@@ -128,7 +127,6 @@ __all__ = [
     "TPU_V5E_LINK_BANDWIDTH",
     "TimingBackend",
     "all_gather",
-    "available_backends",
     "batch_evaluate",
     "bruck_alltoall",
     "cct_of",

@@ -27,8 +27,8 @@ Dispatch rules (the same policy the legacy functions implemented):
   step -- the "no intra-collective reconfiguration overlap" arm the
   model-trace replay compares against).
 * many cells -> the instance-batched grid path (``swot_greedy_grid`` +
-  one batched strawman scoring pass), backend/planner auto-selected by
-  grid size via `repro.core.knobs` thresholds.
+  one batched strawman scoring pass), backend/planner chosen by grid
+  size, mode and platform (`repro.core.ir.backends.route`).
 
 New call sites (the `repro.trace` replay path, benchmarks) use only this
 facade.
@@ -43,16 +43,11 @@ from repro.core.baselines import strawman_decisions, strawman_instance
 from repro.core.fabric import OpticalFabric
 from repro.core.greedy import (
     GridPlan,
+    _routed_grid,
     swot_greedy_chain,
-    swot_greedy_grid,
     swot_greedy_independent,
 )
-from repro.core.ir import batch_evaluate
-from repro.core.ir.backends import (
-    DEFAULT_GRID_BACKEND_THRESHOLD,
-    ENV_GRID_BACKEND_THRESHOLD,
-    select_backend_by_size,
-)
+from repro.core.ir import batch_evaluate, route
 from repro.core.milp import solve_milp
 from repro.core.patterns import Pattern
 from repro.core.schedule import DependencyMode, Schedule
@@ -93,8 +88,8 @@ class PlannerOptions:
     attribution        ``plan_grid(attribution=)``              False
     ================== ======================================== =========
 
-    ``backend=None`` / ``planner=None`` auto-select by grid size (the
-    `repro.core.knobs` thresholds); ``method="strawman"`` is new with
+    ``backend=None`` / ``planner=None`` take the grid's route
+    (`repro.core.ir.backends.route`); ``method="strawman"`` is new with
     the facade -- the lockstep-ICR baseline as a first-class method, so
     replay paths can toggle reconfiguration overlap off per job.
     """
@@ -352,28 +347,27 @@ def _plan_grid(
             f"{opts.method!r} (plan cells one at a time for "
             "milp/strawman)"
         )
-    backend = select_backend_by_size(
+    taken = route(
         len(cells),
-        ENV_GRID_BACKEND_THRESHOLD,
-        DEFAULT_GRID_BACKEND_THRESHOLD,
-        explicit=opts.backend,
-    )
-    plans = swot_greedy_grid(
-        cells,
-        rollout_horizon=opts.rollout_horizon,
-        max_enumerated_planes=opts.max_enumerated_planes,
-        backend=backend,
-        mode=opts.mode,
-        bypass_depth=opts.bypass_depth,
-        independent_split=opts.independent_split,
+        chain=opts.mode is DependencyMode.CHAIN,
         planner=opts.planner,
-        attribution=opts.attribution,
+        backend=opts.backend,
+    )
+    plans = _routed_grid(
+        cells,
+        taken,
+        opts.rollout_horizon,
+        opts.max_enumerated_planes,
+        opts.mode,
+        opts.bypass_depth,
+        opts.independent_split,
+        opts.attribution,
     )
     with host_span("repro.grid.baseline", cells=len(cells)):
         baseline = [
             strawman_instance(fabric, pattern) for fabric, pattern in cells
         ]
-    straw = batch_evaluate(baseline, backend=backend)
+    straw = batch_evaluate(baseline, backend=taken.backend)
     return tuple(
         GridCellPlan(plan=plan, strawman_cct=float(straw.cct[i]))
         for i, plan in enumerate(plans)

@@ -69,16 +69,8 @@ from repro.core.ir import (
     rollout_batch,
     waterfill_batch,
 )
-from repro.core.ir.backends import (
-    DEFAULT_FUSED_PLANNER_THRESHOLD,
-    DEFAULT_GRID_BACKEND_THRESHOLD,
-    ENV_GRID_BACKEND_THRESHOLD,
-    TPU_CHAIN_FUSED_PLANNER_THRESHOLD,
-    get_backend,
-    select_backend_by_size,
-    select_planner_by_size,
-    tpu_chain_rule,
-)
+from repro.core.ir import backends
+from repro.core.ir.backends import Route, get_backend
 from repro.core.ir.engine import pack_instances
 from repro.core.patterns import Pattern
 from repro.core.schedule import (
@@ -454,11 +446,10 @@ def swot_greedy_chain_batch(
     The runtime arbiter's batched-grant path: all jobs granted leases at
     one timestamp become one grid, their reserve-set decisions advance
     through the per-step loop together (``_chain_grid_chosen``, or the
-    fused ``lax.scan`` planner once the batch crosses
-    ``REPRO_FUSED_PLANNER_THRESHOLD``, default
-    ``DEFAULT_FUSED_PLANNER_THRESHOLD`` on every backend: this path has
-    no chip measurement, so the TPU rule of CHAIN sweep grids and its
-    cell buckets do not apply), and each cell is then
+    fused ``lax.scan`` planner from ``FUSED_FROM`` cells on every
+    platform: this path has no chip measurement, so the TPU rule of
+    CHAIN sweep grids and its cell buckets do not apply; `route` with
+    ``batch=True``), and each cell is then
     materialized + polished exactly as ``swot_greedy_chain(polish=True)``
     would.  Because grid decisions are bitwise-identical to the
     per-instance greedy (the property the grid planners are pinned to),
@@ -481,13 +472,16 @@ def swot_greedy_chain_batch(
     assert not any(has_ready_offsets(r) for r in readies), (
         "batched chain planning requires zero ready offsets"
     )
-    planner = select_planner_by_size(len(cells), explicit=None)
+    # Its plans are timed by the object executor, not a backend.
+    route = backends.route(
+        len(cells), chain=True, batch=True, backend="numpy"
+    )
     st = _GridState(
         cells,
         mode=DependencyMode.CHAIN,
         max_enumerated_planes=max_enumerated_planes,
     )
-    decisions = _chain_grid_decisions(st, rollout_horizon, planner)
+    decisions = _chain_grid_decisions(st, rollout_horizon, route)
     from repro.core.milp import lp_polish
 
     schedules: list[Schedule] = []
@@ -1048,30 +1042,27 @@ _RELAY_SPANS = ("repro.relay.call", "repro.relay.call",
 def _chain_grid_decisions(
     st: _GridState,
     rollout_horizon: int,
-    planner: str = "step",
+    route: Route,
     spans: tuple[str, str, str] = _BASE_SPANS,
 ) -> list[Decisions]:
     """Materialize CHAIN-mode grid Decisions from either planner.
 
-    ``planner="step"`` runs the per-step numpy loop
+    ``route.planner="step"`` runs the per-step numpy loop
     (`_chain_grid_chosen`); ``"fused"`` runs the whole loop as one jitted
     ``lax.scan`` on device (`repro.core.ir.fused`) -- bitwise-identical
     chosen splits by contract (property-tested), so the materialization
-    below is shared verbatim.  The fused scan runs at
-    `fused.chain_cells_padded` cells (a power-of-two bucket under the
-    chip rule).  ``spans`` names the host spans of the fused call, the
-    step loop and the materialization (`_BASE_SPANS`, `_RELAY_SPANS`).
+    below is shared verbatim.  The fused scan runs at ``route.cells``
+    cells (a power-of-two bucket under the chip rule).  ``spans`` names
+    the host spans of the fused call, the step loop and the
+    materialization (`_BASE_SPANS`, `_RELAY_SPANS`).
     """
     b = len(st.cells)
     with_bypass = st.bypass_depth >= 2
     fused_span, step_span, materialize_span = spans
-    if planner == "fused":
-        from repro.core.ir.fused import (
-            chain_cells_padded,
-            fused_chain_grid_chosen,
-        )
+    if route.planner == "fused":
+        from repro.core.ir.fused import fused_chain_grid_chosen
 
-        b_pad = chain_cells_padded(b)
+        b_pad = route.cells
         with host_span(fused_span, cells=b, padded=b_pad):
             chosen = fused_chain_grid_chosen(st, rollout_horizon, b_pad)
     else:
@@ -1248,12 +1239,9 @@ def swot_greedy_grid(
     respectively (property-tested); the final CCT/utilization scoring
     runs through ``batch_evaluate`` on the chosen IR backend.
 
-    ``backend=None`` auto-selects jax once the grid reaches
-    ``REPRO_GRID_BACKEND_THRESHOLD`` cells (default
-    ``DEFAULT_GRID_BACKEND_THRESHOLD``; the arbiter's shared
-    `select_backend_by_size` policy), else follows the
-    ``REPRO_IR_BACKEND``/numpy default; an explicit ``backend`` always
-    wins.
+    ``backend=None`` takes the route's choice (`repro.core.ir.backends.
+    route`): jax from ``GRID_JAX_FROM`` cells, numpy below it; an
+    explicit ``backend`` always wins.
 
     ``bypass_depth >= 2`` (CHAIN mode) plans a Topology-Bypassing twin
     grid and keeps, per cell, whichever decisions score the strictly
@@ -1265,13 +1253,10 @@ def swot_greedy_grid(
     numpy loop, one batched dispatch per step), ``"fused"`` (the whole
     loop as ONE jitted ``lax.scan`` device program,
     `repro.core.ir.fused` -- bitwise-identical decisions by contract),
-    or ``None`` to auto-select fused once the grid reaches
-    ``REPRO_FUSED_PLANNER_THRESHOLD`` cells (`select_planner_by_size`).
-    Without the variable a grid takes fused from
-    ``DEFAULT_FUSED_PLANNER_THRESHOLD`` cells (set on the CPU), except a
-    smaller CHAIN grid on a TPU, which takes it from
-    ``TPU_CHAIN_FUSED_PLANNER_THRESHOLD`` (measured on one TPU v5e) with
-    the scan's cell axis padded to a power of two, one compile a bucket.
+    or ``None`` for the route's choice: fused from ``FUSED_FROM`` cells
+    (set on the CPU), except a smaller CHAIN grid on a TPU, which takes
+    it from ``TPU_CHAIN_FUSED_FROM`` (measured on one TPU v5e) with the
+    scan's cell axis padded to a power of two, one compile a bucket.
 
     ``attribution=True`` threads the CCT decomposition through the final
     scoring pass: each returned ``GridPlan.attribution`` carries its
@@ -1284,47 +1269,56 @@ def swot_greedy_grid(
     cell), so the grid path trades it away for throughput; sweeps that
     need polished cells can re-run the winners through ``swot_greedy``.
     """
+    route = backends.route(
+        len(cells),
+        chain=mode is DependencyMode.CHAIN,
+        planner=planner,
+        backend=backend,
+    )
+    return _routed_grid(
+        cells, route, rollout_horizon, max_enumerated_planes, mode,
+        bypass_depth, independent_split, attribution,
+    )
+
+
+def _routed_grid(
+    cells: Sequence[tuple[OpticalFabric, Pattern]],
+    route: Route,
+    rollout_horizon: int,
+    max_enumerated_planes: int,
+    mode: DependencyMode,
+    bypass_depth: int,
+    independent_split: bool,
+    attribution: bool,
+) -> list[GridPlan]:
+    """`swot_greedy_grid` on a route already taken: `repro.core.api`
+    routes a grid once and times its strawman on the same backend."""
     if not cells:
         return []
     if independent_split and mode is DependencyMode.CHAIN:
         raise ValueError(
             "independent_split=True requires mode=INDEPENDENT"
         )
-    backend = select_backend_by_size(
-        len(cells),
-        ENV_GRID_BACKEND_THRESHOLD,
-        DEFAULT_GRID_BACKEND_THRESHOLD,
-        explicit=backend,
-    )
-    planner = select_planner_by_size(
-        len(cells),
-        explicit=planner,
-        default_threshold=(
-            TPU_CHAIN_FUSED_PLANNER_THRESHOLD
-            if mode is DependencyMode.CHAIN and tpu_chain_rule(len(cells))
-            else DEFAULT_FUSED_PLANNER_THRESHOLD
-        ),
-    )
     with host_span("repro.grid.state", cells=len(cells)):
         st = _GridState(cells, mode=mode,
                         max_enumerated_planes=max_enumerated_planes)
     if mode is DependencyMode.CHAIN:
-        decisions = _chain_grid_decisions(st, rollout_horizon, planner)
+        decisions = _chain_grid_decisions(st, rollout_horizon, route)
         if bypass_depth >= 2:
             decisions = _relay_twin_pass(
                 cells, decisions, rollout_horizon, max_enumerated_planes,
-                bypass_depth, planner,
+                bypass_depth, route,
             )
     elif independent_split:
-        decisions = _independent_split_grid_decisions(st, planner)
+        decisions = _independent_split_grid_decisions(st, route.planner)
     else:
-        decisions = _independent_grid_decisions(st, planner)
+        decisions = _independent_grid_decisions(st, route.planner)
     result = batch_evaluate(
         [
             BatchInstance(fabric, pattern, dec)
             for (fabric, pattern), dec in zip(st.cells, decisions)
         ],
-        backend=backend,
+        backend=route.backend,
         attribution=attribution,
     )
     return [
@@ -1394,7 +1388,7 @@ def _relay_twin_pass(
     rollout_horizon: int,
     max_enumerated_planes: int,
     bypass_depth: int,
-    planner: str,
+    route: Route,
 ) -> list[Decisions]:
     """Plan the grid's Topology-Bypassing twin and keep, per cell, its
     plan where it has relays and strictly beats the base plan's CCT.
@@ -1419,7 +1413,7 @@ def _relay_twin_pass(
     if not st.depth_tab.any():
         return decisions
     twin = _chain_grid_decisions(
-        st, rollout_horizon, planner, spans=_RELAY_SPANS
+        st, rollout_horizon, route, spans=_RELAY_SPANS
     )
     with host_span("repro.relay.pick", cells=len(cells)):
         base_cct = _numpy_ccts(cells, decisions)

@@ -6,19 +6,18 @@ The package splits the pre-refactor ``repro.core.ir`` module in two:
   converters, vectorized legality, CCT reductions), the batched sweep
   packer, and the greedy's water-fill/rollout primitives.
 * `repro.core.ir.backends` -- the per-step timing recurrence behind a
-  backend interface: ``numpy`` (reference), ``jax`` (jit + scan over
-  power-of-two buckets), ``pallas`` (blocked-scan kernel in
-  `repro.kernels.timing_scan`, interpret mode off the TPU), and `x64`,
-  the scoped 64-bit mode every device entry point runs under.
+  backend interface: ``numpy`` (reference) and ``jax`` (jit + scan over
+  power-of-two buckets); `x64`, the scoped 64-bit mode every device
+  entry point runs under; and `route`, the one choice of planner,
+  backend and padding a grid takes.
 * `repro.core.ir.fused`    -- the fused on-device grid planner: the
   whole per-step greedy loop (`repro.core.greedy.swot_greedy_grid`) as
   one jitted ``lax.scan``, bitwise-identical to the per-step numpy
-  planner.  Auto-selected by grid size, mode and device
-  (`select_planner_by_size`).
+  planner.  Chosen by grid size, mode and device (`route`).
 
 Every pre-refactor import (``from repro.core.ir import batch_evaluate``)
 keeps working; ``batch_evaluate``/``evaluate_decisions`` gained a
-``backend=`` parameter (env default: ``REPRO_IR_BACKEND``, else numpy).
+``backend=`` parameter (default numpy).
 """
 
 from repro.core.ir.backends import (
@@ -26,14 +25,11 @@ from repro.core.ir.backends import (
     BackendUnavailable,
     JaxBackend,
     NumpyBackend,
-    PallasBackend,
+    Route,
     TimingBackend,
-    available_backends,
-    default_backend_name,
     get_backend,
     resolve_backend,
-    select_backend_by_size,
-    select_planner_by_size,
+    route,
     x64,
 )
 from repro.core.ir.engine import (
@@ -70,14 +66,12 @@ __all__ = [
     "KIND_XMIT",
     "NO_CONFIG",
     "NumpyBackend",
-    "PallasBackend",
+    "Route",
     "ScheduleIR",
     "TimingBackend",
     "_BIG",
     "_pack",
-    "available_backends",
     "batch_evaluate",
-    "default_backend_name",
     "evaluate_decisions",
     "execute_ir",
     "fabric_arrays",
@@ -87,8 +81,7 @@ __all__ = [
     "pack_instances",
     "resolve_backend",
     "rollout_batch",
-    "select_backend_by_size",
-    "select_planner_by_size",
+    "route",
     "to_ir",
     "validate_ir",
     "waterfill_batch",

@@ -1,4 +1,5 @@
-"""Pluggable timing backends for the batched schedule-IR sweep engine.
+"""Timing backends for the batched schedule-IR sweep engine, and the
+one route every grid takes through the planners and backends.
 
 ``batch_evaluate`` packs a batch of (fabric, pattern, decisions) cells
 into flat padded arrays (`repro.core.ir.engine.pack_instances`); a
@@ -9,7 +10,7 @@ recurrence -- the max-plus update
     end     = start + volume / bandwidth
     barrier = max over active planes of end
 
-with lazy per-plane reconfiguration -- across the whole batch.  Three
+with lazy per-plane reconfiguration -- across the whole batch.  Two
 implementations share one parity contract (CCTs equal to the object-path
 oracle within `repro.core.tolerances`):
 
@@ -20,31 +21,23 @@ oracle within `repro.core.tolerances`):
   power-of-two *buckets* (batch, steps, planes) so the number of
   distinct compiled programs stays O(log^3) of the largest sweep, not
   one per sweep shape.  Runs in float64 under the scoped `x64` helper.
-* ``pallas`` -- the recurrence lowered as a *blocked scan* kernel
-  (`repro.kernels.timing_scan`): the grid blocks the batch dimension,
-  each program carries the (block, planes) plane state through a
-  ``fori_loop`` over steps.  It runs in interpret mode off the TPU (the
-  tier-1 suite exercises it) and is compiled on the TPU.
 
-Select a backend per call (``batch_evaluate(..., backend="jax")``) or
-process-wide with the ``REPRO_IR_BACKEND`` env var; unset means numpy so
-results stay deterministic unless an accelerator path is asked for.
+Name a backend per call (``batch_evaluate(..., backend="jax")``); unset
+means numpy.  The grid planners and the arbiter take theirs from
+`route`, which also picks the grid planner and the fused scan's padding
+from the cell count, the mode and the platform.
 """
 
 from __future__ import annotations
 
 import sys
-from typing import Callable
+from typing import Callable, NamedTuple
 
 import numpy as np
 
-from repro.core import knobs
 from repro.core.ir.engine import (
     BatchResult,
     finalize_result,
-)
-from repro.core.knobs import (  # noqa: F401  (compat re-exports)
-    ENV_IR_BACKEND as ENV_BACKEND,
 )
 from repro.core.tolerances import EPS_VOLUME, REL_TOL, TOL
 
@@ -308,7 +301,7 @@ def _require_jax():
 def x64():
     """Context manager scoping 64-bit floats and ints for device programs.
 
-    Every jax, Pallas and fused-planner entry point runs under it: the
+    Every jax timing and fused-planner entry point runs under it: the
     parity contract is with the float64 numpy reference, while the
     process-wide JAX default stays 32-bit for everything else.
     """
@@ -519,75 +512,11 @@ class JaxBackend(TimingBackend):
 
 
 # ---------------------------------------------------------------------------
-# Pallas backend: blocked-scan kernel (interpret mode off the TPU)
-# ---------------------------------------------------------------------------
-class PallasBackend(TimingBackend):
-    """Blocked-scan Pallas kernel (`repro.kernels.timing_scan`).
-
-    Compiled on the TPU, interpreted on every other platform (the path
-    the CPU tests exercise).  ``interpret`` overrides that for tests.
-    """
-
-    name = "pallas"
-
-    def __init__(self, interpret: bool | None = None) -> None:
-        _require_jax()
-        try:
-            # Deferred so numpy-only users never import pallas; jax can
-            # be importable while jax.experimental.pallas is not (old
-            # jax), so this probe is wrapped too.
-            from repro.kernels import timing_scan
-        except Exception as exc:
-            raise BackendUnavailable(
-                "the 'pallas' IR backend needs a jax with a working "
-                f"jax.experimental.pallas ({exc})"
-            ) from exc
-
-        self._kernel = timing_scan.timing_scan
-        self._interpret_override = interpret
-
-    @property
-    def interpret(self) -> bool:
-        if self._interpret_override is not None:
-            return self._interpret_override
-        import jax
-
-        return jax.default_backend() != "tpu"
-
-    def derive_timing(
-        self, packed: dict[str, np.ndarray], attribution: bool = False
-    ) -> BatchResult:
-        b, s, p = packed["vol"].shape
-        padded = pad_packed(packed, _bucket(b), s, _bucket(p))
-        with x64():
-            out = self._kernel(
-                padded, interpret=self.interpret, attribution=attribution
-            )
-        cct, n_recfg, busy, feasible, volume_ok = out[:5]
-        att = None
-        if attribution:
-            # Four component cubes straight from the kernel (xmit,
-            # bypass, exposed wait, hidden), already in finalize order.
-            att = tuple(np.asarray(a)[:b, :, :p] for a in out[5:])
-        return finalize_result(
-            np.asarray(cct)[:b],
-            np.asarray(n_recfg)[:b],
-            np.asarray(busy)[:b, :p],
-            np.asarray(feasible)[:b],
-            np.asarray(volume_ok)[:b],
-            packed["plane_mask"],
-            attribution=att,
-            step_mask=packed["step_mask"] if attribution else None,
-        )
-
-
-# ---------------------------------------------------------------------------
-# Registry + selection
+# Registry + route
 # ---------------------------------------------------------------------------
 BACKENDS: dict[str, type[TimingBackend]] = {
     "numpy": NumpyBackend,
     "jax": JaxBackend,
-    "pallas": PallasBackend,
 }
 
 _instances: dict[str, TimingBackend] = {}
@@ -609,151 +538,96 @@ def get_backend(name: str) -> TimingBackend:
     return _instances[name]
 
 
-def default_backend_name() -> str:
-    """The process-wide default (``REPRO_IR_BACKEND``, else numpy)."""
-    return knobs.ir_backend()
-
-
 def resolve_backend(
     backend: str | TimingBackend | None,
 ) -> TimingBackend:
-    """Per-call selection: instance > name > env default."""
+    """Per-call selection: instance > name > numpy."""
     if isinstance(backend, TimingBackend):
         return backend
-    return get_backend(backend if backend is not None else
-                       default_backend_name())
+    return get_backend(backend if backend is not None else "numpy")
 
 
-def available_backends() -> tuple[str, ...]:
-    """Names of the backends whose dependencies import on this host."""
-    names = []
-    for name in BACKENDS:
-        try:
-            get_backend(name)
-        except BackendUnavailable:
-            continue
-        names.append(name)
-    return tuple(names)
+# Grids of this many cells or more are timed on jax; smaller ones stay on
+# numpy, where jit dispatch does not pay for itself (set on the CPU).
+GRID_JAX_FROM = 64
+# Grids of this many cells or more plan with the fused scan
+# (`repro.core.ir.fused`); below it, on the CPU, the scan's trace and
+# compile did not pay for themselves.
+FUSED_FROM = 256
+# CHAIN sweep grids under FUSED_FROM on a TPU: there the fused scan
+# (program loaded) beat the per-step numpy loop about 10x at every size
+# measured on one TPU v5e, from one cell (benchmarks/planner_crossover.py,
+# PERF.md), so every such grid takes it, its cell axis padded to a power
+# of two so that each bucket compiles once.  Not the arbiter's batched
+# grants, which run on the event path and have no chip cell.
+TPU_CHAIN_FUSED_FROM = 1
 
 
-# Batch size at and above which the grid planners (`swot_greedy_grid` /
-# `plan_grid`) auto-select the jax backend for their scoring passes;
-# small grids stay on numpy (jit dispatch does not amortize).  Override
-# with the env var; <= 0 disables auto-selection.  (Both names are
-# defined in `repro.core.knobs` and re-exported here for compat.)
-ENV_GRID_BACKEND_THRESHOLD = knobs.ENV_GRID_BACKEND_THRESHOLD
-DEFAULT_GRID_BACKEND_THRESHOLD = knobs.DEFAULT_GRID_BACKEND_THRESHOLD
+class Route(NamedTuple):
+    """How one batch is planned and timed (`route`)."""
+
+    planner: str  # "step" (the per-step numpy loop) | "fused" (one scan)
+    backend: "str | TimingBackend"  # the timing backend of its scoring
+    cells: int  # the cell count the fused CHAIN scan runs at
 
 
-def select_backend_by_size(
-    n_rows: int,
-    env_var: str,
-    default_threshold: int,
-    explicit: "str | TimingBackend | None" = None,
-) -> "str | TimingBackend | None":
-    """Threshold-based jax auto-selection for batched evaluation passes.
-
-    The single policy shared by the runtime arbiter's lease re-scoring
-    and the grid planners: an ``explicit`` backend always wins; otherwise
-    jax is selected once the batch reaches the threshold read from
-    ``env_var`` (falling back to ``default_threshold``) -- large batches
-    amortize jit dispatch while small ones are faster on the numpy
-    reference -- and ``None`` (the ``REPRO_IR_BACKEND`` env default) is
-    returned when jax is unavailable or the threshold is not met.  A
-    threshold <= 0 disables auto-selection.
-    """
-    if explicit is not None:
-        return explicit
-    threshold = knobs.int_knob(env_var, default_threshold)
-    if threshold <= 0 or n_rows < threshold:
-        return None
+def _jax_ok() -> bool:
     try:
         get_backend("jax")
     except BackendUnavailable:
-        # Large batch but no jax: fall through to the env default --
-        # EXCEPT when that default is the pallas interpreter, which on a
-        # large batch times the interpreter, not the kernel.  Route
-        # those to the numpy reference instead (auto-selection must
-        # never choose pallas-interpret for large batches).
-        if default_backend_name() == "pallas":
-            try:
-                if get_backend("pallas").interpret:
-                    return "numpy"
-            except BackendUnavailable:
-                pass
-        return None
-    return "jax"
-
-
-# Grid-cell count at and above which the grid planners auto-select the
-# FUSED on-device planner (`repro.core.ir.fused`): the whole per-step
-# greedy loop as one jitted lax.scan.  Two defaults:
-#
-# * CHAIN sweep grids (``swot_greedy_grid`` / ``plan_grid``) of fewer
-#   than DEFAULT_FUSED_PLANNER_THRESHOLD cells on a TPU switch at
-#   TPU_CHAIN_FUSED_PLANNER_THRESHOLD, measured on one TPU v5e with the
-#   program loaded (`benchmarks/planner_crossover.py`, PERF.md): there
-#   the numpy loop's few thousand small host dispatches a grid cost more
-#   than the scan (`tpu_chain_rule`).  Under the same rule a fused CHAIN
-#   call pads the scan's cell axis to a power of two
-#   (`fused.chain_cells_padded`), so each bucket compiles once; the first
-#   grid of a bucket in a process pays that compile or a cache read.
-# * Every other grid keeps DEFAULT_FUSED_PLANNER_THRESHOLD, set on the
-#   CPU, unpadded: every other backend, INDEPENDENT grids, CHAIN grids of
-#   DEFAULT_FUSED_PLANNER_THRESHOLD cells or more, and the arbiter's
-#   batched grants (``swot_greedy_chain_batch``, which runs on the event
-#   path and has no chip cell yet).
-#
-# Where the device's float64 is IEEE (the CPU) the two planners are
-# bitwise-identical, so there the threshold is purely a performance
-# knob.  On a TPU, whose float64 is emulated, the fused planner's split
-# volumes differ in their last bits and rare near-tie choices flip, so a
-# cell's plan depends on which planner its grid took (ROADMAP speed
-# item 3).
-# The env var overrides both defaults; <= 0 disables fused auto-selection.
-ENV_FUSED_PLANNER_THRESHOLD = knobs.ENV_FUSED_PLANNER_THRESHOLD
-DEFAULT_FUSED_PLANNER_THRESHOLD = knobs.DEFAULT_FUSED_PLANNER_THRESHOLD
-TPU_CHAIN_FUSED_PLANNER_THRESHOLD = knobs.TPU_CHAIN_FUSED_PLANNER_THRESHOLD
-
-
-def tpu_chain_rule(n_cells: int) -> bool:
-    """Whether a CHAIN grid of ``n_cells`` falls under the chip's rule:
-    fewer than DEFAULT_FUSED_PLANNER_THRESHOLD cells, and this process's
-    JAX runs on a TPU.
-
-    False without importing JAX where nothing has imported it yet, so a
-    plan that stays on numpy never starts the XLA runtime for this.
-    """
-    if n_cells >= DEFAULT_FUSED_PLANNER_THRESHOLD:
         return False
+    return True
+
+
+def _on_tpu() -> bool:
+    """Whether this process's JAX runs on a TPU; False without importing
+    JAX where nothing has imported it yet, so a plan that stays on numpy
+    never starts the XLA runtime for this."""
     jax = sys.modules.get("jax")
     return jax is not None and jax.default_backend() == "tpu"
 
 
-def select_planner_by_size(
+def route(
     n_cells: int,
-    explicit: str | None = None,
-    default_threshold: int = DEFAULT_FUSED_PLANNER_THRESHOLD,
-) -> str:
-    """Threshold policy for the grid planner implementation.
+    *,
+    chain: bool,
+    batch: bool = False,
+    planner: str | None = None,
+    backend: "str | TimingBackend | None" = None,
+    jax_from: int = GRID_JAX_FROM,
+) -> Route:
+    """The planner, timing backend and padded cell count of a batch.
 
-    Returns ``"fused"`` (one-program ``lax.scan`` planner) once the grid
-    reaches ``REPRO_FUSED_PLANNER_THRESHOLD`` cells (falling back to
-    ``default_threshold``) and jax is importable, else ``"step"`` (the
-    per-step numpy loop).  An ``explicit`` planner always wins; a
-    threshold <= 0 disables auto-selection.
+    The one place that reads the platform, the mode and the cell count
+    for this choice.  ``chain`` says the grid plans in CHAIN mode;
+    ``batch`` marks the arbiter's batches (its batched grants and its
+    lease re-scoring), which keep FUSED_FROM on every platform, unpadded.
+    An explicit ``planner`` or ``backend`` wins.  Otherwise:
+
+    * timing takes jax from ``jax_from`` rows (GRID_JAX_FROM for grids;
+      the arbiter passes its own), numpy below it;
+    * planning takes the fused scan from FUSED_FROM cells, or from
+      TPU_CHAIN_FUSED_FROM for a CHAIN grid under FUSED_FROM on a TPU,
+      the per-step loop below it;
+    * that TPU rule pads the fused CHAIN scan's cell axis to the next
+      power of two (`_bucket`), so grids of one bucket share one
+      compiled program; every other grid keeps its size.
+
+    Where the device's float64 is IEEE (the CPU) the two planners are
+    bitwise-identical, so there the choice is purely one of speed.  On a
+    TPU, whose float64 is emulated, rare near-tie choices flip, so a
+    cell's plan depends on which planner its grid took.
     """
-    if explicit is not None:
-        if explicit not in ("step", "fused"):
-            raise ValueError(
-                f"unknown planner {explicit!r}; choose 'step' or 'fused'"
-            )
-        return explicit
-    threshold = knobs.fused_planner_threshold(default_threshold)
-    if threshold <= 0 or n_cells < threshold:
-        return "step"
-    try:
-        get_backend("jax")
-    except BackendUnavailable:
-        return "step"
-    return "fused"
+    if planner not in (None, "step", "fused"):
+        raise ValueError(
+            f"unknown planner {planner!r}; choose 'step' or 'fused'"
+        )
+    if backend is None:
+        backend = "jax" if n_cells >= jax_from and _jax_ok() else "numpy"
+    # After the backend: a grid that chose jax has imported it by now.
+    chip_rule = chain and not batch and n_cells < FUSED_FROM and _on_tpu()
+    if planner is None:
+        fused_from = TPU_CHAIN_FUSED_FROM if chip_rule else FUSED_FROM
+        planner = "fused" if n_cells >= fused_from and _jax_ok() else "step"
+    padded = planner == "fused" and chip_rule
+    return Route(planner, backend, _bucket(n_cells) if padded else n_cells)

@@ -21,10 +21,9 @@ This module is the struct-of-arrays twin:
   derived directly from ``Decisions`` volume splits, vectorized over a
   *batch* of instances packed into one padded array set.  The per-step
   timing recurrence runs on a pluggable array backend
-  (`repro.core.ir.backends`): ``numpy`` (reference), ``jax`` (jit + scan
-  over padded sweep cells), or ``pallas`` (blocked-scan kernel,
-  interpret mode on CPU).  Select with ``backend=`` or the
-  ``REPRO_IR_BACKEND`` env var; the default is numpy for determinism.
+  (`repro.core.ir.backends`): ``numpy`` (reference) or ``jax`` (jit +
+  scan over padded sweep cells).  Select with ``backend=``; the default
+  is numpy for determinism.
 * ``waterfill_batch`` / ``rollout_batch`` -- the greedy scheduler's
   water-filling and rollout scoring, vectorized over candidate reserve
   sets (used by `repro.core.greedy`) and over lease candidates (used by
@@ -35,7 +34,7 @@ This module is the struct-of-arrays twin:
   without leaving the device.
 
 The packed batch layout is deliberately jit-friendly (flat float64/int64
-arrays, static shapes after padding): the jax and Pallas backends consume
+arrays, static shapes after padding): the jax backend consumes
 it unchanged, and static-shape bucketing (pad to powers of two) keeps the
 number of distinct compiled programs bounded.
 """
@@ -534,7 +533,7 @@ def finalize_result(
     """Assemble a ``BatchResult`` from raw recurrence outputs.
 
     One shared epilogue for every backend, so the utilization formula (and
-    its tolerance behavior) cannot drift between numpy, jax, and Pallas.
+    its tolerance behavior) cannot drift between numpy and jax.
     ``attribution`` optionally carries the raw ``(t_xmit, t_bypass,
     t_recfg_wait, t_recfg_hidden)`` component arrays, each (B, S, P);
     the closing idle term is derived *here* (one canonical float
@@ -578,7 +577,7 @@ def pack_instances(
     The packed dict is the contract between the sweep engine and the
     timing backends (`repro.core.ir.backends`): every array is a plain
     float64/int64/bool NumPy array with batch dimension first, so backends
-    can consume it unchanged (the jax/Pallas backends additionally pad to
+    can consume it unchanged (the jax backend additionally pads to
     static-shape buckets before compiling).
     """
     b = len(instances)
@@ -703,9 +702,8 @@ def batch_evaluate(
     Instances are padded to the batch's max step/plane counts; padded cells
     carry zero volume and are masked out.  ``plane_ready`` optionally gives
     per-instance plane ready-time offsets (the arbiter's re-planning case).
-    ``backend`` selects the timing engine (``"numpy"`` | ``"jax"`` |
-    ``"pallas"``, a ``TimingBackend`` instance, or ``None`` for the
-    ``REPRO_IR_BACKEND`` env default).  ``attribution=True`` additionally
+    ``backend`` selects the timing engine (``"numpy"`` | ``"jax"``, a
+    ``TimingBackend`` instance, or ``None`` for numpy).  ``attribution=True`` additionally
     returns the per-(instance, step, plane) CCT decomposition on
     ``BatchResult.attribution`` (`repro.obs.attribution`); the default
     leaves the hot path untouched.

@@ -55,7 +55,7 @@ from typing import TYPE_CHECKING
 
 import numpy as np
 
-from repro.core.ir.backends import _bucket, tpu_chain_rule, x64
+from repro.core.ir.backends import x64
 from repro.core.ir.engine import _BIG, NO_CONFIG
 from repro.core.tolerances import EPS as _EPS
 from repro.core.tolerances import EPS_VOLUME as _EPS_VOLUME
@@ -623,20 +623,6 @@ def _base_tables(st: "_GridState") -> dict:
     return _on_device(_host_tables(st, _BASE_DTYPES))
 
 
-def chain_cells_padded(n_cells: int) -> int:
-    """The cell count the fused CHAIN scan runs a grid of ``n_cells`` at.
-
-    A grid under the chip's rule (`repro.core.ir.backends.tpu_chain_rule`:
-    under ``DEFAULT_FUSED_PLANNER_THRESHOLD`` cells on a TPU) is padded to
-    the next power of two, the rule the jax timing backend applies to its
-    batch axis (`repro.core.ir.backends._bucket`): grids of one bucket
-    share one compiled program.  Everywhere else a grid keeps its size.
-    Steps and planes are not padded; the pattern sets them, and they take
-    few distinct values.
-    """
-    return _bucket(n_cells) if tpu_chain_rule(n_cells) else n_cells
-
-
 # Tables indexed by candidate row, not by cell.
 _ROW_KEYS = ("cand_mask", "cand_inst", "dyn_row", "dyn_size")
 # A padded cell's entries where zero is not inert: no config installed
@@ -721,8 +707,8 @@ def fused_chain_grid_chosen(
     Returns the same per-step ``(live_insts, split, byp_h)`` tuples the
     numpy loop (`greedy._chain_grid_decisions`) accumulates -- bitwise
     identical -- for the shared Decisions materialization epilogue.
-    ``b_pad`` pads the scan's cell axis (`chain_cells_padded`); the
-    padded cells' outputs are cut off first.  Raises the same
+    ``b_pad`` pads the scan's cell axis (`repro.core.ir.backends.route`
+    gives it); the padded cells' outputs are cut off first.  Raises the same
     "no feasible reserve set" assertion on infeasible steps.
     """
     _require_jax()

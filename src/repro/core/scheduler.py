@@ -11,7 +11,7 @@ pre-facade implementations (parity-tested in tests/test_trace.py).
 pattern) cells is planned by the instance-batched greedy
 (`repro.core.greedy.swot_greedy_grid`) and scored -- including the
 strawman baseline for every cell -- in two ``batch_evaluate`` passes on
-the selected IR backend (numpy / jax / pallas).
+the selected IR backend (numpy / jax).
 """
 
 from __future__ import annotations
@@ -147,10 +147,9 @@ def plan_grid(
     every (fabric, pattern) cell together (``swot_greedy_grid``), then
     ONE more ``batch_evaluate`` pass scores the strawman-ICR baseline
     for every cell -- both on the selected IR backend.  ``backend=None``
-    auto-selects jax once the grid reaches
-    ``REPRO_GRID_BACKEND_THRESHOLD`` cells (the arbiter's shared
-    ``select_backend_by_size`` policy; else the ``REPRO_IR_BACKEND``
-    env default), and an explicit ``backend`` always wins.  ``mode``
+    takes jax once the grid reaches ``GRID_JAX_FROM`` cells, numpy below
+    it (`repro.core.ir.backends.route`), and an explicit ``backend``
+    always wins.  ``mode``
     picks the per-cell planner: CHAIN (paper-faithful reserve-set
     greedy, optionally with Topology-Bypassing relay candidates via
     ``bypass_depth >= 2``) or INDEPENDENT (least-finish-time step
@@ -163,8 +162,8 @@ def plan_grid(
     ``planner`` picks the loop implementation: ``"step"`` (per-step
     numpy), ``"fused"`` (the whole loop as one jitted ``lax.scan`` on
     device, `repro.core.ir.fused` -- bitwise-identical decisions), or
-    ``None`` to auto-select by grid size, mode and device
-    (`select_planner_by_size`).  ``attribution=True`` threads the per-cell CCT decomposition
+    ``None`` to choose by grid size, mode and device (`route`).
+    ``attribution=True`` threads the per-cell CCT decomposition
     (`repro.obs.attribution.Attribution`) through the scoring pass onto
     each ``GridCellPlan.plan.attribution`` -- composes with both
     planners and every backend.

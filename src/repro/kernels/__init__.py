@@ -3,11 +3,6 @@
 Each kernel: ``<name>.py`` (pl.pallas_call + explicit BlockSpec VMEM
 tiling), a jit'd wrapper in ``ops.py``, and a pure-jnp oracle in
 ``ref.py``; all validated in interpret mode on CPU (TPU is the target).
-
-``timing_scan`` is the schedule-IR batched timing recurrence (its
-oracle is the numpy backend in `repro.core.ir.backends`, not ``ref``);
-it is imported lazily by the pallas IR backend so numpy-only users
-never pay the pallas import.
 """
 
 from repro.kernels import ops, ref

@@ -205,7 +205,7 @@ def build_attribution(
 
     The shared epilogue for every timing backend (called from
     ``repro.core.ir.engine.finalize_result``), so the conservation
-    construction cannot drift between numpy, jax, and Pallas.
+    construction cannot drift between numpy and jax.
     """
     cct = np.asarray(cct, dtype=np.float64)
     arrs = tuple(

@@ -24,17 +24,18 @@ monkeypatch the environment without reloading modules.
 from __future__ import annotations
 
 import json
+import os
 import sys
 from typing import Any, TextIO
 
-from repro.core import knobs
-from repro.core.knobs import ENV_LOG  # noqa: F401  (compat re-export)
+ENV_LOG = "REPRO_LOG"
 
 _LEVELS = {"debug": 10, "info": 20, "warning": 30}
 
 
 def _mode() -> str:
-    return knobs.log_mode()
+    """The normalized ``REPRO_LOG`` mode (lowercased, stripped)."""
+    return os.environ.get(ENV_LOG, "").strip().lower()
 
 
 def _threshold(mode: str) -> int:

@@ -71,8 +71,7 @@ from repro.core.ir import (
     BatchInstance,
     batch_evaluate,
 )
-from repro.core import knobs
-from repro.core.ir.backends import select_backend_by_size
+from repro.core.ir.backends import route
 from repro.core.patterns import Pattern, get_pattern
 from repro.core.schedule import DependencyMode, Kind, Schedule
 from repro.core.scheduler import swot_schedule
@@ -86,19 +85,16 @@ from repro.core.tolerances import EPS as _EPS
 # evaluation covers all of them).
 _MAX_RELEASE_CANDIDATES = 16
 
-# Candidate-batch size at and above which the arbiter auto-selects the
-# jax IR backend for lease re-scoring (numpy below it -- small batches
-# cannot amortize jit dispatch).  The default equals the candidate cap,
-# so exactly the maximum-size shrink batches -- the only ones where the
-# batched recurrence dominates the evaluation -- flip to jax; it must
-# stay <= _MAX_RELEASE_CANDIDATES or auto-selection becomes unreachable.
-# Override with the env var; <= 0 disables auto-selection entirely.
-# Name and default live in `repro.core.knobs` (single read point).
-ENV_BACKEND_THRESHOLD = knobs.ENV_ARBITER_BACKEND_THRESHOLD
-_DEFAULT_BACKEND_THRESHOLD = knobs.DEFAULT_ARBITER_BACKEND_THRESHOLD
-assert _DEFAULT_BACKEND_THRESHOLD <= _MAX_RELEASE_CANDIDATES, (
-    "auto-selection unreachable: knobs.DEFAULT_ARBITER_BACKEND_THRESHOLD "
-    "must stay <= _MAX_RELEASE_CANDIDATES"
+# Candidate-batch size at and above which lease re-scoring runs on the
+# jax IR backend (numpy below it -- small batches cannot amortize jit
+# dispatch).  It equals the candidate cap, so exactly the maximum-size
+# shrink batches -- the only ones where the batched recurrence dominates
+# the evaluation -- take jax; it must stay <= _MAX_RELEASE_CANDIDATES or
+# jax becomes unreachable.
+_JAX_FROM = 16
+assert _JAX_FROM <= _MAX_RELEASE_CANDIDATES, (
+    "jax re-scoring unreachable: _JAX_FROM must stay <= "
+    "_MAX_RELEASE_CANDIDATES"
 )
 
 # Lease placement policies (see class docstring).
@@ -342,10 +338,9 @@ class FabricArbiter:
         self.allow_independent = allow_independent
         self.rebalance = rebalance
         self.placement = placement
-        # IR backend for batched lease-shrink re-scoring.  None enables
-        # auto-selection: jax once the candidate batch reaches
-        # REPRO_ARBITER_BACKEND_THRESHOLD rows, the REPRO_IR_BACKEND env
-        # default (numpy) below it (see `_select_backend`).
+        # IR backend for batched lease-shrink re-scoring.  None takes
+        # jax once the candidate batch reaches _JAX_FROM rows, numpy
+        # below it (see `_select_backend`).
         self.backend = backend
         # Structured tracing (repro.obs.trace).  The default NULL_TRACER
         # has enabled=False; every site below guards on that flag, so the
@@ -1005,22 +1000,20 @@ class FabricArbiter:
             self._schedule_boundary(job)
 
     # -- backend selection --------------------------------------------------
-    def _select_backend(self, n_candidates: int) -> str | None:
+    def _select_backend(self, n_candidates: int) -> str:
         """IR backend for a batched re-scoring of ``n_candidates`` rows.
 
-        An explicit arbiter ``backend`` always wins.  Otherwise the jax
-        backend is auto-selected once the candidate batch reaches
-        ``REPRO_ARBITER_BACKEND_THRESHOLD`` rows (default
-        ``_DEFAULT_BACKEND_THRESHOLD``) -- the shared
-        `repro.core.ir.backends.select_backend_by_size` policy, which the
-        grid planners apply with their own threshold env too.
+        An explicit arbiter ``backend`` always wins.  Otherwise the
+        batch's route (`repro.core.ir.backends.route`) takes jax from
+        ``_JAX_FROM`` rows, numpy below it.
         """
-        return select_backend_by_size(
+        return route(
             n_candidates,
-            ENV_BACKEND_THRESHOLD,
-            _DEFAULT_BACKEND_THRESHOLD,
-            explicit=self.backend,
-        )
+            chain=True,
+            batch=True,
+            backend=self.backend,
+            jax_from=_JAX_FROM,
+        ).backend
 
     # -- plan surgery -------------------------------------------------------
     def _cut_plan(self, job: _Job, cutoff: float) -> None:
@@ -1310,7 +1303,7 @@ class FabricArbiter:
         sub_pattern: Pattern,
         prof: tuple,
         candidates: list[tuple[int, ...]],
-        backend: str | None,
+        backend: str,
         job: _Job,
         now: float,
     ) -> None:

@@ -433,7 +433,7 @@ class TestBypassBatchPadding:
             out.append(BatchInstance(fabric, pattern, dec))
         return out
 
-    @pytest.mark.parametrize("backend", ["numpy", "jax", "pallas"])
+    @pytest.mark.parametrize("backend", ["numpy", "jax"])
     def test_padded_bypass_cells_never_leak(self, backend):
         instances = self._mixed_instances()
         try:
@@ -461,16 +461,13 @@ class TestBypassBatchPadding:
             for i in instances
         ]
         np.testing.assert_array_equal(ref.cct, objs)
-        for name in ("jax", "pallas"):
-            try:
-                res = batch_evaluate(instances, backend=name)
-            except BackendUnavailable:
-                continue
-            np.testing.assert_allclose(
-                res.cct, ref.cct, atol=TOL, err_msg=name
-            )
-            np.testing.assert_array_equal(res.feasible, ref.feasible)
-            np.testing.assert_array_equal(res.volume_ok, ref.volume_ok)
+        try:
+            res = batch_evaluate(instances, backend="jax")
+        except BackendUnavailable as exc:
+            pytest.skip(str(exc))
+        np.testing.assert_allclose(res.cct, ref.cct, atol=TOL)
+        np.testing.assert_array_equal(res.feasible, ref.feasible)
+        np.testing.assert_array_equal(res.volume_ok, ref.volume_ok)
 
 
 class TestIndependentSplit:
@@ -520,56 +517,37 @@ class TestIndependentSplit:
 
 
 class TestGridBackendSelection:
-    def test_threshold_env_and_explicit(self, monkeypatch):
+    def test_threshold_env_and_explicit(self):
         from repro.core.ir.backends import (
-            DEFAULT_GRID_BACKEND_THRESHOLD,
-            ENV_GRID_BACKEND_THRESHOLD,
+            GRID_JAX_FROM,
             BackendUnavailable,
             get_backend,
-            select_backend_by_size,
+            route,
         )
 
-        monkeypatch.delenv(ENV_GRID_BACKEND_THRESHOLD, raising=False)
-        assert select_backend_by_size(
-            1, ENV_GRID_BACKEND_THRESHOLD, DEFAULT_GRID_BACKEND_THRESHOLD
-        ) is None
+        assert route(1, chain=True).backend == "numpy"
+        assert route(GRID_JAX_FROM - 1, chain=True).backend == "numpy"
         try:
             get_backend("jax")
             expected = "jax"
         except BackendUnavailable:
-            expected = None
-        assert select_backend_by_size(
-            DEFAULT_GRID_BACKEND_THRESHOLD,
-            ENV_GRID_BACKEND_THRESHOLD,
-            DEFAULT_GRID_BACKEND_THRESHOLD,
-        ) == expected
-        # Explicit always wins; <= 0 disables.
-        assert select_backend_by_size(
-            1 << 20, ENV_GRID_BACKEND_THRESHOLD, 64, explicit="numpy"
-        ) == "numpy"
-        monkeypatch.setenv(ENV_GRID_BACKEND_THRESHOLD, "0")
-        assert select_backend_by_size(
-            1 << 20, ENV_GRID_BACKEND_THRESHOLD, 64
-        ) is None
-        monkeypatch.setenv(ENV_GRID_BACKEND_THRESHOLD, "nope")
-        with pytest.raises(ValueError, match="must be an integer"):
-            select_backend_by_size(1, ENV_GRID_BACKEND_THRESHOLD, 64)
+            expected = "numpy"
+        assert route(GRID_JAX_FROM, chain=True).backend == expected
+        # An explicit backend always wins, either way.
+        assert route(1 << 20, chain=True, backend="numpy").backend == "numpy"
+        assert route(1, chain=True, backend="jax").backend == "jax"
 
-    def test_small_grid_results_unchanged_by_threshold(self, monkeypatch):
-        """Auto-selection changes only the scoring backend, never the
-        decisions."""
-        from repro.core.ir.backends import ENV_GRID_BACKEND_THRESHOLD
-
+    def test_small_grid_results_unchanged_by_threshold(self):
+        """The scoring backend changes only the scores' last bits, never
+        the decisions."""
         pattern = get_pattern("pairwise_alltoall", 6, 8e6)
         cells = [
             (OpticalFabric(6, p, t_recfg=2e-4), pattern) for p in (2, 3)
         ]
-        monkeypatch.setenv(ENV_GRID_BACKEND_THRESHOLD, "1")
         try:
-            forced = swot_greedy_grid(cells)
+            forced = swot_greedy_grid(cells, backend="jax")
         except BackendUnavailable:
             pytest.skip("jax unavailable")
-        monkeypatch.setenv(ENV_GRID_BACKEND_THRESHOLD, "0")
         plain = swot_greedy_grid(cells)
         for a, b in zip(forced, plain):
             assert a.decisions == b.decisions

@@ -5,17 +5,15 @@ Contract under test (`repro.core.ir.fused` + the ``planner=`` plumbing):
 * the fused ``lax.scan`` planner produces BITWISE-identical decisions to
   the per-step numpy loop in every mode x bypass x split combination
   (property-tested over random grids);
-* the pallas timing kernel handles Topology-Bypassing batches natively
-  (no numpy delegation) with bitwise CCT/attribution parity across
-  padding shapes, and padded cells never leak into real cells;
 * ``attribution=True`` composes with the fused planner;
 * where the CHAIN scan's cell axis is padded to a power of two (CHAIN
   grids under the default threshold on a TPU), decisions stay
   bitwise-equal to the step loop's, padded instances never reach the
   per-step tuples, and grids of one bucket share one compiled program;
   elsewhere each grid keeps its own shape;
-* ``select_planner_by_size`` honors threshold / env / explicit choice,
-  only CHAIN sweep grids under the default threshold on a TPU take the
+* ``route`` honors threshold and explicit choice, gives the planner,
+  backend and padded cells of the table below on both platforms, only
+  CHAIN sweep grids under the default threshold on a TPU take the
   chip's threshold, and a grid planned on numpy never imports jax;
 * the fused planner's numeric primitives (`_no_fma` FMA guard, the
   odd-even sorting network, pairwise stable ranks, the column-wise
@@ -35,20 +33,17 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from repro.core import BatchInstance, OpticalFabric, batch_evaluate
+from repro.core import OpticalFabric
 from repro.core import greedy
 from repro.core.greedy import _GridState, swot_greedy_grid
 from repro.core.ir.backends import (
-    BackendUnavailable,
-    DEFAULT_FUSED_PLANNER_THRESHOLD,
-    ENV_FUSED_PLANNER_THRESHOLD,
-    TPU_CHAIN_FUSED_PLANNER_THRESHOLD,
+    FUSED_FROM,
+    TPU_CHAIN_FUSED_FROM,
     _bucket,
-    get_backend,
-    select_planner_by_size,
+    route,
     x64,
 )
-from repro.core.ir.engine import _BIG, pack_instances, waterfill_batch
+from repro.core.ir.engine import _BIG, waterfill_batch
 from repro.core.patterns import pairwise_alltoall, rabenseifner_allreduce
 from repro.core.schedule import DependencyMode
 from repro.core.scheduler import plan_grid
@@ -222,7 +217,7 @@ class TestBucketedChain:
         assert fused._SCAN_CACHE[("chain", 24, False)]._cache_size() == 1
 
     def test_off_the_chip_each_grid_keeps_its_shape(self, monkeypatch):
-        assert fused.chain_cells_padded(3) == 3
+        assert route(3, chain=True, planner="fused").cells == 3
         monkeypatch.setattr(fused, "_SCAN_CACHE", {})
         for n_cells in (3, 5):
             swot_greedy_grid(_sweep(n_cells), planner="fused")
@@ -256,38 +251,56 @@ class TestFusedAttribution:
 
 
 # ---------------------------------------------------------------------------
-# Planner auto-selection policy
+# The route: planner, timing backend and padded cells
 # ---------------------------------------------------------------------------
+# planner/backend/cells a grid takes, by platform and mode, at 1, 63, 64,
+# 255, 256 and 1024 cells (the table the route replaced three selectors
+# with, read from them).
+_ROUTE_TABLE = {
+    ("cpu", "CHAIN"): ("step/numpy/1", "step/numpy/63", "step/jax/64",
+                       "step/jax/255", "fused/jax/256", "fused/jax/1024"),
+    ("cpu", "INDEPENDENT"): ("step/numpy/1", "step/numpy/63", "step/jax/64",
+                             "step/jax/255", "fused/jax/256",
+                             "fused/jax/1024"),
+    ("tpu", "CHAIN"): ("fused/numpy/1", "fused/numpy/64", "fused/jax/64",
+                       "fused/jax/256", "fused/jax/256", "fused/jax/1024"),
+    ("tpu", "INDEPENDENT"): ("step/numpy/1", "step/numpy/63", "step/jax/64",
+                             "step/jax/255", "fused/jax/256",
+                             "fused/jax/1024"),
+}
+_ROUTE_CELLS = (1, 63, 64, 255, 256, 1024)
+
+
+@pytest.mark.parametrize(
+    "platform, mode, n_cells, want",
+    [
+        (platform, mode, n, want)
+        for (platform, mode), row in _ROUTE_TABLE.items()
+        for n, want in zip(_ROUTE_CELLS, row)
+    ],
+)
+def test_route_table(monkeypatch, platform, mode, n_cells, want):
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    taken = route(n_cells, chain=mode == "CHAIN")
+    assert f"{taken.planner}/{taken.backend}/{taken.cells}" == want
+
+
 class TestSelectPlanner:
-    def test_threshold_default(self, monkeypatch):
-        monkeypatch.delenv(ENV_FUSED_PLANNER_THRESHOLD, raising=False)
-        at = DEFAULT_FUSED_PLANNER_THRESHOLD
-        assert select_planner_by_size(at - 1) == "step"
-        assert select_planner_by_size(at) == "fused"
+    def test_threshold_default(self):
+        at = FUSED_FROM
+        assert route(at - 1, chain=True).planner == "step"
+        assert route(at, chain=True).planner == "fused"
 
-    def test_env_override(self, monkeypatch):
-        monkeypatch.setenv(ENV_FUSED_PLANNER_THRESHOLD, "1")
-        assert select_planner_by_size(1) == "fused"
-        monkeypatch.setenv(ENV_FUSED_PLANNER_THRESHOLD, "100000")
-        assert select_planner_by_size(1024) == "step"
-
-    def test_explicit_wins(self, monkeypatch):
-        monkeypatch.setenv(ENV_FUSED_PLANNER_THRESHOLD, "1")
-        assert select_planner_by_size(9999, explicit="step") == "step"
-        assert select_planner_by_size(1, explicit="fused") == "fused"
+    def test_explicit_wins(self):
+        assert route(9999, chain=True, planner="step").planner == "step"
+        assert route(1, chain=True, planner="fused").planner == "fused"
 
     def test_rejects_unknown(self):
         with pytest.raises(ValueError, match="planner"):
-            select_planner_by_size(4, explicit="magic")
-
-    def test_bad_env(self, monkeypatch):
-        monkeypatch.setenv(ENV_FUSED_PLANNER_THRESHOLD, "soon")
-        with pytest.raises(ValueError):
-            select_planner_by_size(4)
+            route(4, chain=True, planner="magic")
 
     def test_chain_grid_off_the_tpu_keeps_the_default(self, monkeypatch):
-        monkeypatch.delenv(ENV_FUSED_PLANNER_THRESHOLD, raising=False)
-        at = DEFAULT_FUSED_PLANNER_THRESHOLD
+        at = FUSED_FROM
         for n_cells, want in ((at - 1, "step"), (at, "fused")):
             taken = _planner_taken(
                 monkeypatch, "_chain_grid_decisions",
@@ -308,7 +321,6 @@ class TestSelectPlanner:
         )
         src = os.path.join(os.path.dirname(__file__), os.pardir, "src")
         env = {**os.environ, "PYTHONPATH": os.path.abspath(src)}
-        env.pop(ENV_FUSED_PLANNER_THRESHOLD, None)
         done = subprocess.run(
             [sys.executable, "-c", code], env=env,
             capture_output=True, text=True, timeout=120,
@@ -321,10 +333,11 @@ class _Chosen(Exception):
 
 
 def _planner_taken(monkeypatch, target: str, call) -> str:
-    """The ``planner`` argument ``call()`` hands ``greedy.<target>``."""
+    """The planner ``call()`` hands ``greedy.<target>``: its last
+    argument, a `Route` (CHAIN) or the planner's name (INDEPENDENT)."""
 
     def spy(st, *args):
-        raise _Chosen(args[-1])
+        raise _Chosen(getattr(args[-1], "planner", args[-1]))
 
     monkeypatch.setattr(greedy, target, spy)
     with pytest.raises(_Chosen) as got:
@@ -338,7 +351,6 @@ class TestSelectPlannerOnTpu:
     @pytest.fixture(autouse=True)
     def _tpu(self, monkeypatch):
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        monkeypatch.delenv(ENV_FUSED_PLANNER_THRESHOLD, raising=False)
 
     def _chain(self, monkeypatch, n_cells, **kw):
         return _planner_taken(
@@ -347,22 +359,21 @@ class TestSelectPlannerOnTpu:
         )
 
     def test_chain_grid_switches_at_the_chip_threshold(self, monkeypatch):
-        at = TPU_CHAIN_FUSED_PLANNER_THRESHOLD
-        assert 1 <= at < DEFAULT_FUSED_PLANNER_THRESHOLD
+        at = TPU_CHAIN_FUSED_FROM
+        assert 1 <= at < FUSED_FROM
         assert self._chain(monkeypatch, at) == "fused"
         assert self._chain(monkeypatch, 3) == "fused"
-        assert self._chain(monkeypatch, DEFAULT_FUSED_PLANNER_THRESHOLD - 1) \
-            == "fused"
+        assert self._chain(monkeypatch, FUSED_FROM - 1) == "fused"
         # One cell below it (no grid at all where it is 1) keeps the loop.
-        assert select_planner_by_size(at - 1, default_threshold=at) == "step"
+        assert route(at - 1, chain=True).planner == "step"
 
     def test_only_the_chip_rule_buckets(self, monkeypatch):
-        at = DEFAULT_FUSED_PLANNER_THRESHOLD
-        assert fused.chain_cells_padded(1) == 1
-        assert fused.chain_cells_padded(3) == 4
-        assert fused.chain_cells_padded(70) == 128
-        assert fused.chain_cells_padded(at - 1) == at
-        assert fused.chain_cells_padded(at + 44) == at + 44
+        at = FUSED_FROM
+        assert route(1, chain=True).cells == 1
+        assert route(3, chain=True).cells == 4
+        assert route(70, chain=True).cells == 128
+        assert route(at - 1, chain=True).cells == at
+        assert route(at + 44, chain=True).cells == at + 44
         b_pad = {}
 
         def spy(st, horizon, pad=None):
@@ -378,7 +389,7 @@ class TestSelectPlannerOnTpu:
         assert b_pad == {70: 128, at - 1: at, at + 44: at + 44}
 
     def test_independent_grid_keeps_the_default(self, monkeypatch):
-        at = DEFAULT_FUSED_PLANNER_THRESHOLD
+        at = FUSED_FROM
         for n_cells, want in ((at - 1, "step"), (at, "fused")):
             taken = _planner_taken(
                 monkeypatch, "_independent_grid_decisions",
@@ -389,7 +400,7 @@ class TestSelectPlannerOnTpu:
             assert taken == want
 
     def test_chain_batch_keeps_the_default(self, monkeypatch):
-        at = DEFAULT_FUSED_PLANNER_THRESHOLD
+        at = FUSED_FROM
         for n_cells, want in ((at - 1, "step"), (at, "fused")):
             taken = _planner_taken(
                 monkeypatch, "_chain_grid_decisions",
@@ -398,92 +409,12 @@ class TestSelectPlannerOnTpu:
             assert taken == want
 
     def test_env_and_explicit_still_win(self, monkeypatch):
-        at = TPU_CHAIN_FUSED_PLANNER_THRESHOLD
+        at = TPU_CHAIN_FUSED_FROM
         assert self._chain(monkeypatch, at, planner="step") == "step"
         assert self._chain(monkeypatch, 70, planner="step") == "step"
-        monkeypatch.setenv(ENV_FUSED_PLANNER_THRESHOLD, "100")
-        assert self._chain(monkeypatch, 70) == "step"
-        assert self._chain(monkeypatch, 100) == "fused"
-        monkeypatch.setenv(ENV_FUSED_PLANNER_THRESHOLD, "100000")
-        assert self._chain(monkeypatch, at) == "step"
         assert self._chain(monkeypatch, at, planner="fused") == "fused"
-        monkeypatch.setenv(ENV_FUSED_PLANNER_THRESHOLD, "1")
-        assert self._chain(monkeypatch, 1) == "fused"
-        assert self._chain(monkeypatch, at, planner="step") == "step"
-
-
-# ---------------------------------------------------------------------------
-# Pallas kernel: native bypass batches, padding parity, no delegation
-# ---------------------------------------------------------------------------
-def _bypass_instances(n: int) -> list[BatchInstance]:
-    """n bypass-winning cells (pre-staged rotations, high t_recfg)."""
-    pattern = pairwise_alltoall(8, 8e6)
-    cells = [
-        (
-            OpticalFabric(
-                8, 4, t_recfg=3.2e-3 * (1 + 0.1 * i)
-            ).prestaged(pattern.steps[0].config),
-            pattern,
-        )
-        for i in range(n)
-    ]
-    plans = swot_greedy_grid(cells, backend="numpy", bypass_depth=2)
-    assert any(
-        plan.decisions.bypass is not None and any(plan.decisions.bypass)
-        for plan in plans
-    ), "fixture produced no relays; bypass leg would be vacuous"
-    return [
-        BatchInstance(fabric, pattern, plan.decisions)
-        for (fabric, pattern), plan in zip(cells, plans)
-    ]
-
-
-class TestPallasBypass:
-    @pytest.fixture()
-    def pallas(self):
-        try:
-            return get_backend("pallas")
-        except BackendUnavailable as exc:
-            pytest.skip(f"pallas unavailable: {exc}")
-
-    # Batch sizes straddling the padding buckets (1 -> 1, 3 -> 4,
-    # 5 -> 8): padded rows must not perturb the real bypass cells.
-    @pytest.mark.parametrize("n", [1, 3, 5])
-    def test_bypass_parity_across_padding(self, pallas, n):
-        instances = _bypass_instances(n)
-        ref = batch_evaluate(instances, backend="numpy", attribution=True)
-        got = batch_evaluate(instances, backend="pallas", attribution=True)
-        assert np.array_equal(got.cct, ref.cct)
-        assert np.array_equal(
-            got.n_reconfigurations, ref.n_reconfigurations
-        )
-        for field in ("t_xmit", "t_bypass", "t_recfg_wait",
-                      "t_recfg_hidden", "t_idle"):
-            assert np.array_equal(
-                getattr(got.attribution, field),
-                getattr(ref.attribution, field),
-            ), f"pallas attribution field {field} diverges on bypass"
-
-    def test_no_numpy_delegation(self, pallas, monkeypatch):
-        """The kernel itself must evaluate bypass batches.
-
-        Pre-PR the pallas backend silently handed any batch containing
-        relay routes to ``_timing_numpy``; sabotaging that fallback
-        proves the kernel path is the one running.
-        """
-        import repro.core.ir.backends as B
-
-        instances = _bypass_instances(2)  # planned before the sabotage
-
-        def boom(*args, **kwargs):
-            raise AssertionError(
-                "pallas delegated a bypass batch to numpy"
-            )
-
-        monkeypatch.setattr(B, "_timing_numpy", boom)
-        packed = pack_instances(instances, None)
-        result = pallas.derive_timing(packed)
-        assert np.all(result.feasible)
+        taken = route(70, chain=True, planner="fused", backend="numpy")
+        assert taken == ("fused", "numpy", 128)
 
 
 # ---------------------------------------------------------------------------

@@ -455,7 +455,7 @@ def test_attribution_parity_optimize_on_off(runtime_trace, fabric):
         )
 
 
-@pytest.mark.parametrize("backend", ["numpy", "jax", "pallas"])
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
 def test_attribution_conserved_on_every_backend(
     runtime_trace, fabric, backend
 ):

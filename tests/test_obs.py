@@ -51,7 +51,7 @@ from repro.runtime.workload import JobSpec
 
 def _available_backends():
     names = []
-    for name in ("numpy", "jax", "pallas"):
+    for name in ("numpy", "jax"):
         try:
             get_backend(name)
         except BackendUnavailable:

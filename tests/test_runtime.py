@@ -280,59 +280,50 @@ def test_moe_config_mix_includes_alltoall():
 
 
 # -- arbiter IR-backend auto-selection --------------------------------------
-def test_backend_auto_selection_threshold(monkeypatch):
-    """Below the candidate threshold the arbiter stays on the env default
-    (numpy); at/above it, jax is auto-selected when importable.  The
-    default threshold must stay reachable: it cannot exceed the
-    lease-shrink candidate cap, or the sole call site could never
-    trigger auto-selection."""
+def test_backend_auto_selection_threshold():
+    """Below the candidate threshold the arbiter stays on numpy; at/above
+    it, jax is selected when importable.  The threshold must stay
+    reachable: it cannot exceed the lease-shrink candidate cap, or the
+    sole call site could never select jax."""
     from repro.core.ir import BackendUnavailable, get_backend
-    from repro.runtime.arbiter import (
-        _DEFAULT_BACKEND_THRESHOLD,
-        _MAX_RELEASE_CANDIDATES,
-    )
+    from repro.runtime.arbiter import _JAX_FROM, _MAX_RELEASE_CANDIDATES
 
-    monkeypatch.delenv("REPRO_ARBITER_BACKEND_THRESHOLD", raising=False)
-    assert _DEFAULT_BACKEND_THRESHOLD <= _MAX_RELEASE_CANDIDATES
+    assert _JAX_FROM <= _MAX_RELEASE_CANDIDATES
     arbiter = FabricArbiter(SimEngine(), OpticalFabric(8, 4))
-    assert arbiter._select_backend(1) is None
-    assert (
-        arbiter._select_backend(_DEFAULT_BACKEND_THRESHOLD - 1) is None
-    )
+    assert arbiter._select_backend(1) == "numpy"
+    assert arbiter._select_backend(_JAX_FROM - 1) == "numpy"
     try:
         get_backend("jax")
         expected = "jax"
     except BackendUnavailable:
-        expected = None  # falls back to the env default
-    assert (
-        arbiter._select_backend(_DEFAULT_BACKEND_THRESHOLD) == expected
-    )
+        expected = "numpy"
+    assert arbiter._select_backend(_JAX_FROM) == expected
 
 
 def test_backend_auto_selection_env_override(monkeypatch):
     from repro.core.ir import BackendUnavailable, get_backend
+    from repro.runtime import arbiter as arbiter_mod
 
     arbiter = FabricArbiter(SimEngine(), OpticalFabric(8, 4))
-    monkeypatch.setenv("REPRO_ARBITER_BACKEND_THRESHOLD", "2")
+    monkeypatch.setattr(arbiter_mod, "_JAX_FROM", 2)
     try:
         get_backend("jax")
         assert arbiter._select_backend(2) == "jax"
     except BackendUnavailable:
-        assert arbiter._select_backend(2) is None
-    assert arbiter._select_backend(1) is None
-    # <= 0 disables auto-selection entirely.
-    monkeypatch.setenv("REPRO_ARBITER_BACKEND_THRESHOLD", "0")
-    assert arbiter._select_backend(10**6) is None
-    monkeypatch.setenv("REPRO_ARBITER_BACKEND_THRESHOLD", "nope")
-    with pytest.raises(ValueError, match="must be an integer"):
-        arbiter._select_backend(5)
+        assert arbiter._select_backend(2) == "numpy"
+    assert arbiter._select_backend(1) == "numpy"
+    # Past every batch: jax is never selected.
+    monkeypatch.setattr(arbiter_mod, "_JAX_FROM", 10**9)
+    assert arbiter._select_backend(10**6) == "numpy"
 
 
 def test_backend_explicit_choice_wins_over_auto_selection(monkeypatch):
+    from repro.runtime import arbiter as arbiter_mod
+
     arbiter = FabricArbiter(
         SimEngine(), OpticalFabric(8, 4), backend="numpy"
     )
-    monkeypatch.setenv("REPRO_ARBITER_BACKEND_THRESHOLD", "1")
+    monkeypatch.setattr(arbiter_mod, "_JAX_FROM", 1)
     assert arbiter._select_backend(10**6) == "numpy"
 
 
@@ -340,7 +331,9 @@ def test_shrink_rescoring_runs_through_auto_selected_backend(monkeypatch):
     """End-to-end: with a threshold of 1 every lease-shrink re-scoring
     batch goes through the auto-selected backend; results (and therefore
     the shared-fabric outcome) must match the numpy-pinned run."""
-    monkeypatch.setenv("REPRO_ARBITER_BACKEND_THRESHOLD", "1")
+    from repro.runtime import arbiter as arbiter_mod
+
+    monkeypatch.setattr(arbiter_mod, "_JAX_FROM", 1)
     pytest.importorskip("jax")
 
     def run(backend):

@@ -12,8 +12,7 @@ the chip: the topology is built inside a fixture, never at import, so
 every test worker collects the same tests and only the worker running
 this file loads the TPU compiler.
 
-Also pins how the Pallas backend picks interpret mode, and where the
-persistent compilation cache goes.
+Also pins where the persistent compilation cache goes.
 """
 
 import os
@@ -35,7 +34,6 @@ from repro.core import greedy as _greedy  # noqa: E402
 from repro.core.baselines import strawman_instance  # noqa: E402
 from repro.core.ir import fused, x64  # noqa: E402
 from repro.core.ir.backends import (  # noqa: E402
-    PallasBackend,
     _build_jax_timing,
     _bucket,
     pad_packed,
@@ -233,15 +231,6 @@ def test_fused_independent_scan_compiles(one_chip):
             .compile()
         )
     assert compiled.memory_analysis() is not None
-
-
-@pytest.mark.parametrize("platform", ["tpu", "cpu", "gpu"])
-def test_pallas_interprets_only_off_the_tpu(monkeypatch, platform):
-    monkeypatch.setattr(jax, "default_backend", lambda: platform)
-    assert PallasBackend().interpret is (platform != "tpu")
-    # An explicit choice still wins (tests force either mode).
-    assert PallasBackend(interpret=True).interpret is True
-    assert PallasBackend(interpret=False).interpret is False
 
 
 @pytest.fixture()

@@ -704,20 +704,3 @@ def test_plan_request_validation():
     assert isinstance(res, PlanResult)
     with pytest.raises(ValueError):
         _ = plan(PlanRequest.grid([(fabric, pat), (fabric, pat)])).cct
-
-
-# ------------------------------------------------------------------- knobs
-
-
-def test_knobs_read_env_per_call(monkeypatch):
-    from repro.core import knobs
-
-    monkeypatch.delenv(knobs.ENV_IR_BACKEND, raising=False)
-    assert knobs.ir_backend() == "numpy"
-    monkeypatch.setenv(knobs.ENV_IR_BACKEND, "jax")
-    assert knobs.ir_backend() == "jax"  # no import-time caching
-    monkeypatch.setenv(knobs.ENV_GRID_BACKEND_THRESHOLD, "123")
-    assert knobs.grid_backend_threshold() == 123
-    desc = knobs.describe()
-    assert knobs.ENV_IR_BACKEND in desc
-    assert desc[knobs.ENV_IR_BACKEND]["effective"] == "jax"

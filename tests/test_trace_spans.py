@@ -163,9 +163,9 @@ def _fused_call_args(tmp_path, n_cells: int) -> dict:
 
 def test_fused_call_span_names_the_padded_bucket(tmp_path, monkeypatch):
     # Where the chip rule applies (a small CHAIN grid on a TPU).
-    from repro.core.ir import fused
+    from repro.core.ir import backends
 
-    monkeypatch.setattr(fused, "tpu_chain_rule", lambda n_cells: True)
+    monkeypatch.setattr(backends, "_on_tpu", lambda: True)
     args = _fused_call_args(tmp_path, 3)
     assert args["cells"] == 3
     assert args["padded"] == 4
